@@ -20,6 +20,9 @@ def main() -> None:
                   help="write machine-readable results to PATH")
   args = ap.parse_args()
 
+  from repro.util import compile_cache
+
+  compile_cache()
   from benchmarks import (common, fig4_exemplar, fig6_active_set,
                           fig8_speedup, fig9_maxcut, fig10_coverage,
                           kernels_bench, query_serving, roofline,

@@ -45,6 +45,8 @@ EPOCH_REPS, APPEND_REPS = 5, 5
 
 
 def _emit_child(name: str, us: float, derived: str, shapes: dict) -> None:
+  import jax
+  shapes = dict(shapes, platform=jax.default_backend())
   print("BENCH " + json.dumps({"name": name, "us": us, "derived": derived,
                                "shapes": shapes}), flush=True)
 
@@ -185,7 +187,9 @@ def run(quick: bool = False) -> None:
   from benchmarks.common import emit
 
   ns = (4096,) if quick else (4096, 16384)
-  env = dict(os.environ)
+  # a forced-host-device smoke suite by design: the child never contends
+  # for an accelerator the parent process may hold
+  env = dict(os.environ, JAX_PLATFORMS="cpu")
   env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                       f" --xla_force_host_platform_device_count={NDEV}"
                       ).strip()

@@ -39,6 +39,8 @@ EPOCH_REPS = 3
 
 
 def _emit_child(name: str, us: float, derived: str, shapes: dict) -> None:
+  import jax
+  shapes = dict(shapes, platform=jax.default_backend())
   print("BENCH " + json.dumps({"name": name, "us": us, "derived": derived,
                                "shapes": shapes}), flush=True)
 
@@ -148,7 +150,9 @@ def _child_lazy(m: int, n: int, kappa: int, kf: int) -> None:
 
 
 def _run_child(ndev: int, args: list[str], timeout: int = 3600) -> list[str]:
-  env = dict(os.environ)
+  # a forced-host-device smoke suite by design: the child never contends
+  # for an accelerator the parent process may hold
+  env = dict(os.environ, JAX_PLATFORMS="cpu")
   env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                       f" --xla_force_host_platform_device_count={ndev}"
                       ).strip()

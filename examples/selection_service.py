@@ -34,7 +34,8 @@ def main():
   ap.add_argument("--batch", type=int, default=8)
   ap.add_argument("--coreset", type=int, default=128)
   ap.add_argument("--mesh", type=int, default=0,
-                  help="forced host devices for the sharded service")
+                  help="devices of the sharded service (forced host devices "
+                  "on CPU)")
   args = ap.parse_args()
 
   if args.mesh:
@@ -60,6 +61,10 @@ def main():
   feats = np.asarray(corpus.features())
   n_half = corpus.n_docs // 2
 
+  if len(jax.devices()) < max(args.mesh, 1):
+    raise SystemExit(f"--mesh {args.mesh} needs {args.mesh} devices; the "
+                     f"{jax.default_backend()} backend has "
+                     f"{len(jax.devices())}")
   mesh = make_mesh((max(args.mesh, 1),), ("data",))
   # the propose/select regime of launch/train.py, at example scale: each
   # machine proposes kappa, the merge selects k_final

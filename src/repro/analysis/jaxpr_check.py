@@ -60,7 +60,7 @@ _REDUCE_PRIMS = {
     "reduce_sum", "reduce_max", "reduce_min", "reduce_prod",
     "reduce_or", "reduce_and", "argmax", "argmin",
 }
-# psum2 is what shard_map's check_rep rewrite turns psum into (jax 0.4.x)
+# psum2 is what shard_map's replication-checking rewrite made of psum (jax 0.4)
 _AXES_COLLECTIVES = {"psum", "psum2", "pmax", "pmin"}
 _NAME_COLLECTIVES = {
     "all_gather", "all_to_all", "ppermute", "pbroadcast", "axis_index",

@@ -438,8 +438,8 @@ def baselines(rng: Array, feats: Array, *, m: int, k: int, objective,
 
 
 def _combined_index(axis_names: tuple[str, ...], mesh) -> Array:
-  """Row-major shard index over ``axis_names`` (static sizes from the mesh;
-  jax 0.4.x has no jax.lax.axis_size)."""
+  """Row-major shard index over ``axis_names`` (static sizes from the mesh,
+  so the index folds to constants per shard)."""
   idx = jax.lax.axis_index(axis_names[0])
   for a in axis_names[1:]:
     idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
@@ -538,14 +538,9 @@ def _tree_mesh(mesh, factors: tuple[int, ...]):
   shape = tuple(reversed(factors))
   names = tuple(f"tree{i}" for i in range(len(shape)))
   devs = mesh.devices.reshape(shape)
-  axis_type = getattr(jax.sharding, "AxisType", None)
-  if axis_type is not None:
-    try:
-      return jax.sharding.Mesh(
-          devs, names, axis_types=(axis_type.Auto,) * len(names)), names
-    except TypeError:
-      pass
-  return jax.sharding.Mesh(devs, names), names
+  return jax.sharding.Mesh(
+      devs, names,
+      axis_types=(jax.sharding.AxisType.Auto,) * len(names)), names
 
 
 def _resolve_merge_mesh(mesh, axis_names, m: int, merge: str,
@@ -871,6 +866,21 @@ def greedi_sharded(feats: Array, *, mesh, kappa: int, k_final: int,
   return shmapped(feats, gids, wb, straggler_keep, rng, age, deadline)
 
 
+def _check_cache_fits(n_local: int, mesh) -> None:
+  """Refuse a cached-similarity epoch whose (n/m, n/m) f32 block cannot fit
+  the device, instead of running it out of memory.  Backends that report no
+  memory limit (the CPU) are not checked."""
+  stats = mesh.devices.flat[0].memory_stats() or {}
+  limit = stats.get("bytes_limit")
+  need = n_local * n_local * 4
+  if limit and need > limit:
+    raise ValueError(
+        f"greedi_sharded_fast caches a ({n_local}, {n_local}) f32 similarity "
+        f"block per shard ({need / 2**30:.1f} GiB), more than the device's "
+        f"{limit / 2**30:.1f} GiB; use more shards or greedi_sharded, which "
+        "streams the similarities")
+
+
 def greedi_sharded_fast(feats: Array, *, mesh, kappa: int, k_final: int,
                         axis_names: tuple[str, ...] = ("data",),
                         kernel: str = "linear",
@@ -929,6 +939,7 @@ def greedi_sharded_fast(feats: Array, *, mesh, kappa: int, k_final: int,
                                          tree_branch)
   n, d = feats.shape
   assert n % m == 0, (n, m)
+  _check_cache_fits(n // m, mesh)
   if straggler_keep is None:
     straggler_keep = jnp.ones((m,), bool)
   if rng is None:

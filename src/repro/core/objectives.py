@@ -44,7 +44,7 @@ Array = jax.Array
 # The masked-gain floor and the lowest-index masked argmax are defined ONCE,
 # in kernels/ref.py (they are the ground-truth semantics every fused select
 # kernel must replicate); re-exported here as the core layer's select path.
-from repro.kernels.ref import NEG, masked_top1  # noqa: E402,F401
+from repro.kernels.ref import DOT_PRECISION, NEG, masked_top1  # noqa: E402,F401
 
 
 def _kernel_h(kernel_kwargs: tuple) -> float:
@@ -58,14 +58,15 @@ def _kernel_h(kernel_kwargs: tuple) -> float:
 
 def linear_kernel(x: Array, y: Array) -> Array:
   """Dot-product similarity. x: (n, d), y: (m, d) -> (n, m)."""
-  return x @ y.T
+  return jnp.matmul(x, y.T, precision=DOT_PRECISION)
 
 
 def rbf_kernel(x: Array, y: Array, h: float = 0.75) -> Array:
   """Squared-exponential kernel exp(-||x-y||^2 / h^2) (paper Sec. 3.4.1)."""
   x2 = jnp.sum(x * x, axis=-1, keepdims=True)
   y2 = jnp.sum(y * y, axis=-1, keepdims=True)
-  d2 = jnp.maximum(x2 - 2.0 * (x @ y.T) + y2.T, 0.0)
+  d2 = jnp.maximum(x2 - 2.0 * jnp.matmul(x, y.T, precision=DOT_PRECISION)
+                   + y2.T, 0.0)
   return jnp.exp(-d2 / (h * h))
 
 
@@ -73,7 +74,7 @@ def neg_sq_dist(x: Array, y: Array) -> Array:
   """-||x-y||^2: the (negated) k-means dissimilarity l = d^2 of Sec. 6.1."""
   x2 = jnp.sum(x * x, axis=-1, keepdims=True)
   y2 = jnp.sum(y * y, axis=-1, keepdims=True)
-  return -(x2 - 2.0 * (x @ y.T) + y2.T)
+  return -(x2 - 2.0 * jnp.matmul(x, y.T, precision=DOT_PRECISION) + y2.T)
 
 
 KERNELS: dict[str, Callable[..., Array]] = {

@@ -11,17 +11,22 @@ for the same contract on backend resolution).
 Three knobs live here:
 
   * ``pick_block(n, d)``   -- tile size along an n-length kernel axis.  On
-    TPU larger candidate tiles amortize grid overhead while a (block, block)
-    f32 similarity tile stays well under VMEM (512^2 * 4 B = 1 MiB); on CPU
-    the kernels only run in interpret mode (parity, not speed), so the table
-    keeps the 256 tiles the parity suite has always exercised.
+    TPU larger candidate tiles amortize grid overhead; on CPU the kernels
+    only run in interpret mode (parity, not speed), so the table keeps the
+    256 tiles the parity suite has always exercised.  Two limits of the
+    chip's compiler hold on every backend, so interpret mode runs the blocks
+    Mosaic would: a block is never narrower than ``LANES`` (row-vector
+    blocks such as the ``(2, block)`` coverage/mask rows must span whole
+    128-lane vregs -- short axes are padded up, not tiled finer), and wide
+    rows shrink the block until the double-buffered tiles fit
+    ``VMEM_BUDGET`` (d = 3072 f32 rows get 128-row tiles).
   * ``lazy_tile(n, d)``    -- rescoring granularity of the tile-bound lazy
     greedy in core/greedy.py.  Bigger tiles mean fewer bound entries and
     better matmul shapes but coarser pruning; the XLA path prefers bigger
     tiles than the TPU path (whose tiles must double-buffer through VMEM).
-  * ``floor_pow2(n, cap)`` -- the legacy fallback: largest power-of-two
-    <= cap that still divides into n without absurd padding (shared with
-    ops.py's explicit-override clamping).
+  * ``floor_pow2(n, cap)`` -- largest power-of-two <= cap that still
+    divides into n without absurd padding (the lazy tiles and ops.py's
+    explicit-override clamping, which then applies the lane floor).
 """
 from __future__ import annotations
 
@@ -34,6 +39,14 @@ import jax
 def default_backend() -> str:
   """Process-wide backend, read once (trace-time contract; see module doc)."""
   return jax.default_backend()
+
+
+# TPU vreg lane count: the narrowest legal last dim of a kernel block
+LANES = 128
+
+# VMEM a kernel's tiles may hold.  v5e scopes 16 MiB per kernel by default;
+# the rest is headroom for Mosaic's own temporaries.
+VMEM_BUDGET = 12 << 20
 
 
 def floor_pow2(n: int, cap: int = 256, floor: int = 8) -> int:
@@ -52,7 +65,22 @@ def _bucket_d(d: int) -> str:
   return "narrow" if d <= 64 else "wide"
 
 
-# (backend, n-bucket, d-bucket) -> kernel block size along the n axis.
+def _tile_bytes(b: int, d: int, itemsize: int) -> int:
+  """VMEM of one grid step of the (b, d) x (b, d) similarity kernels: two
+  double-buffered feature tiles, their f32 working copies, and the (b, b)
+  f32 similarity tile with two elementwise temporaries."""
+  return 4 * b * d * itemsize + 2 * b * d * 4 + 3 * b * b * 4
+
+
+def fit_block(b: int, d: int, itemsize: int = 4) -> int:
+  """Halve ``b`` until its tiles fit ``VMEM_BUDGET``; never below LANES."""
+  while b > LANES and _tile_bytes(b, d, itemsize) > VMEM_BUDGET:
+    b //= 2
+  return max(b, LANES)
+
+
+# (backend, n-bucket, d-bucket) -> kernel block size along the n axis,
+# before the VMEM fit of ``fit_block``.
 _BLOCK_TABLE: dict[tuple[str, str, str], int] = {
     ("tpu", "small", "narrow"): 256,
     ("tpu", "small", "wide"): 256,
@@ -65,13 +93,16 @@ _BLOCK_TABLE: dict[tuple[str, str, str], int] = {
 _DEFAULT_BLOCK = 256
 
 
-def pick_block(n: int, d: int, backend: str | None = None) -> int:
-  """Tile size along an n-length axis for (n, d) operands on ``backend``."""
+def pick_block(n: int, d: int, backend: str | None = None,
+               itemsize: int = 4) -> int:
+  """Tile size along an n-length axis for (n, d) operands on ``backend``
+  (``itemsize`` bytes per element); axes under 256 rows get one LANES
+  block."""
   if n < 256:
-    return floor_pow2(n)
+    return LANES
   backend = backend or default_backend()
-  return _BLOCK_TABLE.get((backend, _bucket_n(n), _bucket_d(d)),
-                          _DEFAULT_BLOCK)
+  b = _BLOCK_TABLE.get((backend, _bucket_n(n), _bucket_d(d)), _DEFAULT_BLOCK)
+  return fit_block(b, d, itemsize)
 
 
 # (backend, d-bucket) -> lazy-greedy rescore tile (core/greedy.py mode="lazy").

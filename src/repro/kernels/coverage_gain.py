@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import DOT_PRECISION
+
 DEFAULT_BM = 256   # eval-tile rows
 DEFAULT_BN = 256   # candidate-tile rows
 
@@ -31,6 +33,7 @@ def _kernel(ev_ref, cd_ref, aux_ref, out_ref, *, kernel: str, h: float):
   msk = aux_ref[2, :].astype(jnp.float32)       # (BM,)
 
   sim = jax.lax.dot_general(ev, cd, (((1,), (1,)), ((), ())),
+                            precision=DOT_PRECISION,
                             preferred_element_type=jnp.float32)  # (BM, BN)
   if kernel == "rbf":
     e2 = jnp.sum(ev * ev, axis=1, keepdims=True)

@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import DOT_PRECISION
+
 DEFAULT_BM = 256   # row-tile size
 DEFAULT_BN = 256   # column-tile size
 
@@ -24,6 +26,7 @@ def _kernel(w_ref, x_ref, out_ref):
   v = 1.0 - 2.0 * x                             # (1, BN)
 
   part = jax.lax.dot_general(w, v, (((1,), (1,)), ((), ())),
+                             precision=DOT_PRECISION,
                              preferred_element_type=jnp.float32)  # (BM, 1)
 
   @pl.when(j == 0)

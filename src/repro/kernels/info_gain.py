@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import DOT_PRECISION
+
 DEFAULT_BN = 256   # candidate-tile rows
 
 
@@ -32,6 +34,7 @@ def _kernel(sel_ref, linv_ref, cd_ref, out_ref, *, kernel: str, h: float,
   cd = cd_ref[...].astype(jnp.float32)          # (BN, d)
 
   k_sc = jax.lax.dot_general(sel, cd, (((1,), (1,)), ((), ())),
+                             precision=DOT_PRECISION,
                              preferred_element_type=jnp.float32)  # (k, BN)
   c2 = jnp.sum(cd * cd, axis=1)                 # (BN,)
   if kernel == "rbf":
@@ -43,6 +46,7 @@ def _kernel(sel_ref, linv_ref, cd_ref, out_ref, *, kernel: str, h: float,
     k_vv = c2
 
   c = jax.lax.dot_general(linv, k_sc, (((1,), (0,)), ((), ())),
+                          precision=DOT_PRECISION,
                           preferred_element_type=jnp.float32)     # (k, BN)
   cond = k_vv + ridge - jnp.sum(c * c, axis=0)
   out_ref[...] = jnp.maximum(cond, 1e-12)[None, :]

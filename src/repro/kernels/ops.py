@@ -1,10 +1,11 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Handles padding to block multiples, dtype promotion, and backend dispatch:
-on the CPU container the kernels execute in interpret mode (the kernel body
+on the CPU backend the kernels execute in interpret mode (the kernel body
 runs as traced jnp ops -- bit-accurate vs the TPU lowering semantics), on TPU
-they compile to Mosaic.  ``force_xla=True`` routes to the pure-jnp reference
-(used to A/B the kernels and by tiny shapes where tiling is overhead).
+they compile to Mosaic, and any other backend is refused.
+``force_xla=True`` routes to the pure-jnp reference (used to A/B the kernels
+and by tiny shapes where tiling is overhead).
 
 Block sizes default to ``None`` = "consult the autotable" (kernels/autotune.py,
 keyed on (n, d, backend)); an explicit block argument still wins, clamped to
@@ -34,8 +35,17 @@ Array = jax.Array
 
 @functools.lru_cache(maxsize=None)
 def _interpret() -> bool:
-  # cached: read the process backend once, at trace time (dispatch.py doc)
-  return jax.default_backend() != "tpu"
+  """Whether the Pallas kernels run in interpret mode: on the CPU backend
+  only.  Any other backend that is not a TPU (a GPU, or a TPU that failed to
+  initialize and fell back elsewhere) is refused instead of silently
+  interpreted at a fraction of the speed.  Cached: the process backend is
+  read once, at trace time (dispatch.py doc)."""
+  backend = jax.default_backend()
+  if backend not in ("tpu", "cpu"):
+    raise RuntimeError(
+        f"the Pallas kernels compile for TPU and interpret on CPU; backend "
+        f"{backend!r} is neither (pass backend='ref' for the XLA oracles)")
+  return backend == "cpu"
 
 
 def _pad_rows(x: Array, mult: int, value=0.0) -> Array:
@@ -47,15 +57,16 @@ def _pad_rows(x: Array, mult: int, value=0.0) -> Array:
                  constant_values=value)
 
 
-def _block(n: int, d: int, explicit: int | None) -> int:
+def _block(n: int, d: int, explicit: int | None, itemsize: int = 4) -> int:
   """Resolve a tile size: explicit override (rounded down to a power of two,
   then clamped to fit n) or the autotable.  The clamp caps at the override
   itself, so any explicit power-of-two block (512, 1024, ...) is honored
-  whenever the operand is big enough."""
+  whenever the operand is big enough.  Either way the block is at least
+  ``autotune.LANES``: a short axis is padded up to one lane-wide block."""
   if explicit is not None:
     cap = 1 << max(int(explicit).bit_length() - 1, 3)  # pow2 <= explicit
-    return autotune.floor_pow2(n, cap=cap)
-  return autotune.pick_block(n, d)
+    return max(autotune.floor_pow2(n, cap=cap), autotune.LANES)
+  return autotune.pick_block(n, d, itemsize=itemsize)
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "h", "block_m",
@@ -70,7 +81,8 @@ def facility_gain(eval_feats: Array, cand_feats: Array, cov: Array,
                                  kernel=kernel, h=h)
   ne, d = eval_feats.shape
   nc = cand_feats.shape[0]
-  bm, bn = _block(ne, d, block_m), _block(nc, d, block_n)
+  sz = eval_feats.dtype.itemsize
+  bm, bn = _block(ne, d, block_m, sz), _block(nc, d, block_n, sz)
   ev = _pad_rows(eval_feats, bm)
   cd = _pad_rows(cand_feats, bn)
   cv = _pad_rows(cov, bm, value=jnp.inf)   # inf cover => padded rows gain 0
@@ -93,7 +105,8 @@ def facility_select(eval_feats: Array, cand_feats: Array, cov: Array,
                                    cand_ok, kernel=kernel, h=h)
   ne, d = eval_feats.shape
   nc = cand_feats.shape[0]
-  bm, bn = _block(ne, d, block_m), _block(nc, d, block_n)
+  sz = eval_feats.dtype.itemsize
+  bm, bn = _block(ne, d, block_m, sz), _block(nc, d, block_n, sz)
   ev = _pad_rows(eval_feats, bm)
   cd = _pad_rows(cand_feats, bn)
   cv = _pad_rows(cov, bm, value=jnp.inf)
@@ -115,7 +128,7 @@ def info_gain_cond(sel_feats: Array, linv: Array, cand_feats: Array, *,
                                   h=h, ridge=ridge)
   k, d = sel_feats.shape
   nc = cand_feats.shape[0]
-  bn = _block(nc, d, block_n)
+  bn = _block(nc, d, block_n, cand_feats.dtype.itemsize)
   kpad = (-k) % 8  # sublane-align the resident selection block
   sl = _pad_rows(sel_feats, 8)
   lv = jnp.pad(linv, ((0, kpad), (0, kpad))) if kpad else linv
@@ -137,7 +150,7 @@ def info_select(sel_feats: Array, linv: Array, cand_feats: Array,
                                kernel=kernel, h=h, ridge=ridge)
   k, d = sel_feats.shape
   nc = cand_feats.shape[0]
-  bn = _block(nc, d, block_n)
+  bn = _block(nc, d, block_n, cand_feats.dtype.itemsize)
   kpad = (-k) % 8
   sl = _pad_rows(sel_feats, 8)
   lv = jnp.pad(linv, ((0, kpad), (0, kpad))) if kpad else linv
@@ -160,7 +173,8 @@ def coverage_gain(eval_feats: Array, cand_feats: Array, cover: Array,
                                  eval_mask, kernel=kernel, h=h)
   ne, d = eval_feats.shape
   nc = cand_feats.shape[0]
-  bm, bn = _block(ne, d, block_m), _block(nc, d, block_n)
+  sz = eval_feats.dtype.itemsize
+  bm, bn = _block(ne, d, block_m, sz), _block(nc, d, block_n, sz)
   ev = _pad_rows(eval_feats, bm)
   cd = _pad_rows(cand_feats, bn)
   cv = _pad_rows(cover, bm)
@@ -184,7 +198,8 @@ def coverage_select(eval_feats: Array, cand_feats: Array, cover: Array,
                                    eval_mask, cand_ok, kernel=kernel, h=h)
   ne, d = eval_feats.shape
   nc = cand_feats.shape[0]
-  bm, bn = _block(ne, d, block_m), _block(nc, d, block_n)
+  sz = eval_feats.dtype.itemsize
+  bm, bn = _block(ne, d, block_m, sz), _block(nc, d, block_n, sz)
   ev = _pad_rows(eval_feats, bm)
   cd = _pad_rows(cand_feats, bn)
   cv = _pad_rows(cover, bm)
@@ -205,7 +220,8 @@ def graph_cut_gain(w: Array, in_s: Array, *, block_m: int | None = None,
   if force_xla:
     return ref.graph_cut_gain_ref(w, in_s)
   n = w.shape[0]
-  bm, bn = _block(n, n, block_m), _block(n, n, block_n)
+  sz = w.dtype.itemsize
+  bm, bn = _block(n, n, block_m, sz), _block(n, n, block_n, sz)
   b = max(bm, bn)
   pad = (-n) % b
   wp = jnp.pad(w, ((0, pad), (0, pad))) if pad else w
@@ -224,7 +240,8 @@ def graph_cut_select(w: Array, in_s: Array, node_ok: Array, *,
   if force_xla:
     return ref.graph_cut_select_ref(w, in_s, node_ok)
   n = w.shape[0]
-  bm, bn = _block(n, n, block_m), _block(n, n, block_n)
+  sz = w.dtype.itemsize
+  bm, bn = _block(n, n, block_m, sz), _block(n, n, block_n, sz)
   b = max(bm, bn)
   pad = (-n) % b
   wp = jnp.pad(w, ((0, pad), (0, pad))) if pad else w
@@ -329,7 +346,8 @@ def pairwise(x: Array, y: Array, *, kernel: str = "rbf", h: float = 0.75,
     return ref.pairwise_ref(x, y, kernel=kernel, h=h)
   nx, ny = x.shape[0], y.shape[0]
   d = x.shape[1]
-  bx, by = _block(nx, d, block_x), _block(ny, d, block_y)
+  sz = x.dtype.itemsize
+  bx, by = _block(nx, d, block_x, sz), _block(ny, d, block_y, sz)
   xp = _pad_rows(x, bx)
   yp = _pad_rows(y, by)
   out = pairwise_pallas(xp, yp, kernel=kernel, h=h, block_x=bx, block_y=by,

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import DOT_PRECISION
+
 DEFAULT_B = 256
 
 
@@ -20,6 +22,7 @@ def _kernel(x_ref, y_ref, out_ref, *, kernel: str, h: float):
   x = x_ref[...].astype(jnp.float32)
   y = y_ref[...].astype(jnp.float32)
   dot = jax.lax.dot_general(x, y, (((1,), (1,)), ((), ())),
+                            precision=DOT_PRECISION,
                             preferred_element_type=jnp.float32)
   if kernel == "rbf":
     x2 = jnp.sum(x * x, axis=1, keepdims=True)

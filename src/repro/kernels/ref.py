@@ -12,6 +12,17 @@ Array = jax.Array
 
 NEG = -1e30  # masked-gain floor shared with the select kernels / greedy loops
 
+# Every f32 contraction of the oracles and kernels runs at full f32
+# precision.  XLA's TPU default rounds f32 matmul operands to bf16, which
+# would put the XLA oracles, the Mosaic kernels and a host reference ~1e-3
+# apart -- past the warm-bound slack and the pallas/ref parity the selection
+# contracts are stated in.  On CPU this is what XLA does anyway.
+DOT_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def _mm(a: Array, b: Array) -> Array:
+  return jnp.matmul(a, b, precision=DOT_PRECISION)
+
 
 def masked_top1(scores: Array, ok: Array, floor: float = NEG):
   """Ground truth for every select oracle: lowest-index argmax of the masked
@@ -25,11 +36,11 @@ def masked_top1(scores: Array, ok: Array, floor: float = NEG):
 
 def _sim(ev: Array, cd: Array, kernel: str, h: float) -> Array:
   if kernel == "linear":
-    return ev @ cd.T
+    return _mm(ev, cd.T)
   if kernel == "rbf":
     e2 = jnp.sum(ev * ev, axis=-1, keepdims=True)
     c2 = jnp.sum(cd * cd, axis=-1, keepdims=True)
-    d2 = jnp.maximum(e2 - 2.0 * (ev @ cd.T) + c2.T, 0.0)
+    d2 = jnp.maximum(e2 - 2.0 * _mm(ev, cd.T) + c2.T, 0.0)
     return jnp.exp(-d2 / (h * h))
   raise ValueError(kernel)
 
@@ -44,7 +55,7 @@ def facility_gain_ref(eval_feats: Array, cand_feats: Array, cov: Array,
   sim = _sim(eval_feats.astype(jnp.float32), cand_feats.astype(jnp.float32),
              kernel, h)
   inc = jnp.maximum(sim - cov.astype(jnp.float32)[:, None], 0.0)
-  return eval_mask.astype(jnp.float32) @ inc
+  return _mm(eval_mask.astype(jnp.float32), inc)
 
 
 def pairwise_ref(x: Array, y: Array, *, kernel: str = "rbf",
@@ -68,7 +79,7 @@ def info_gain_cond_ref(sel_feats: Array, linv: Array, cand_feats: Array, *,
   sel = sel_feats.astype(jnp.float32)
   cd = cand_feats.astype(jnp.float32)
   k_sc = _sim(sel, cd, kernel, h)                       # (k, nc)
-  c = linv.astype(jnp.float32) @ k_sc                   # (k, nc)
+  c = _mm(linv.astype(jnp.float32), k_sc)              # (k, nc)
   if kernel == "rbf":
     k_vv = jnp.ones((cd.shape[0],), jnp.float32)
   else:
@@ -92,13 +103,13 @@ def coverage_gain_ref(eval_feats: Array, cand_feats: Array, cover: Array,
   cap = cap.astype(jnp.float32)
   new = jnp.minimum(cover[:, None] + sim, cap[:, None])
   inc = new - jnp.minimum(cover, cap)[:, None]
-  return eval_mask.astype(jnp.float32) @ inc
+  return _mm(eval_mask.astype(jnp.float32), inc)
 
 
 def graph_cut_gain_ref(w: Array, in_s: Array) -> Array:
   """Per-node cut gains deg_v - 2 (W x)_v == W @ (1 - 2x): (n,) float32."""
   wf = w.astype(jnp.float32)
-  return wf @ (1.0 - 2.0 * in_s.astype(jnp.float32))
+  return _mm(wf, 1.0 - 2.0 * in_s.astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------

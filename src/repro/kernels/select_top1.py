@@ -10,6 +10,8 @@ top-1 (max + lowest-index-of-max) runs in-register once the tile is fully
 accumulated, and a running global (best_gain, best_idx) pair -- the only
 thing that ever leaves the kernel -- is folded across the candidate grid.
 The (n,) gains vector never touches HBM and argmax disappears as a pass.
+The pair is held in lane-wide (1, 128) output blocks (Mosaic refuses scalar
+stores to VMEM); lane 0 is read back.
 
 Semantics shared by every kernel (and their ref.py ground truths):
 
@@ -30,31 +32,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import NEG  # the shared masked-gain floor
+from repro.kernels.autotune import LANES
+from repro.kernels.ref import DOT_PRECISION, NEG
 
 
 def _top1_fold(scores, base, best_ref, idx_ref):
-  """Fold a (1, B) masked score tile into the running (best, idx) pair."""
+  """Fold a (1, B) masked score tile into the running (best, idx) pair.
+
+  The pair lives in lane-wide (1, LANES) VMEM blocks, every lane holding
+  the same value: Mosaic cannot store a scalar to VMEM, so the fold stays
+  in vector ops end to end (keepdims reductions, lane broadcasts)."""
   b = scores.shape[1]
-  m = jnp.max(scores)
+  m = jnp.max(scores, axis=1, keepdims=True)                 # (1, 1)
   iota = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
-  ti = jnp.min(jnp.where(scores == m, iota, b))
-  upd = m > best_ref[0, 0]
-  idx_ref[0, 0] = jnp.where(upd, base + ti, idx_ref[0, 0])
-  best_ref[0, 0] = jnp.where(upd, m, best_ref[0, 0])
+  ti = jnp.min(jnp.where(scores == m, iota, b), axis=1, keepdims=True)
+  best = best_ref[...]
+  upd = m > best                                              # (1, LANES)
+  idx_ref[...] = jnp.where(upd, base + ti, idx_ref[...])
+  best_ref[...] = jnp.where(upd, m, best)
 
 
 def _init_best(best_ref, idx_ref):
-  best_ref[0, 0] = jnp.float32(-jnp.inf)
-  idx_ref[0, 0] = jnp.int32(0)
+  best_ref[...] = jnp.full(best_ref.shape, -jnp.inf, jnp.float32)
+  idx_ref[...] = jnp.zeros(idx_ref.shape, jnp.int32)
 
 
 def _scalar_outs():
   return (
-      (jax.ShapeDtypeStruct((1, 1), jnp.float32),
-       jax.ShapeDtypeStruct((1, 1), jnp.int32)),
-      (pl.BlockSpec((1, 1), lambda *_: (0, 0)),
-       pl.BlockSpec((1, 1), lambda *_: (0, 0))),
+      (jax.ShapeDtypeStruct((1, LANES), jnp.float32),
+       jax.ShapeDtypeStruct((1, LANES), jnp.int32)),
+      (pl.BlockSpec((1, LANES), lambda *_: (0, 0)),
+       pl.BlockSpec((1, LANES), lambda *_: (0, 0))),
   )
 
 
@@ -75,6 +83,7 @@ def _facility_kernel(ev_ref, cd_ref, covm_ref, ok_ref, best_ref, idx_ref,
   msk = covm_ref[1, :].astype(jnp.float32)    # (BM,)
 
   sim = jax.lax.dot_general(ev, cd, (((1,), (1,)), ((), ())),
+                            precision=DOT_PRECISION,
                             preferred_element_type=jnp.float32)  # (BM, BN)
   if kernel == "rbf":
     e2 = jnp.sum(ev * ev, axis=1, keepdims=True)
@@ -154,6 +163,7 @@ def _coverage_kernel(ev_ref, cd_ref, aux_ref, ok_ref, best_ref, idx_ref,
   msk = aux_ref[2, :].astype(jnp.float32)       # (BM,)
 
   sim = jax.lax.dot_general(ev, cd, (((1,), (1,)), ((), ())),
+                            precision=DOT_PRECISION,
                             preferred_element_type=jnp.float32)
   if kernel == "rbf":
     e2 = jnp.sum(ev * ev, axis=1, keepdims=True)
@@ -227,6 +237,7 @@ def _info_kernel(sel_ref, linv_ref, cd_ref, ok_ref, best_ref, idx_ref, *,
   cd = cd_ref[...].astype(jnp.float32)          # (BN, d)
 
   k_sc = jax.lax.dot_general(sel, cd, (((1,), (1,)), ((), ())),
+                             precision=DOT_PRECISION,
                              preferred_element_type=jnp.float32)  # (k, BN)
   c2 = jnp.sum(cd * cd, axis=1)                 # (BN,)
   if kernel == "rbf":
@@ -238,6 +249,7 @@ def _info_kernel(sel_ref, linv_ref, cd_ref, ok_ref, best_ref, idx_ref, *,
     k_vv = c2
 
   c = jax.lax.dot_general(linv, k_sc, (((1,), (0,)), ((), ())),
+                          precision=DOT_PRECISION,
                           preferred_element_type=jnp.float32)     # (k, BN)
   cond = jnp.maximum(k_vv + ridge - jnp.sum(c * c, axis=0), 1e-12)
 
@@ -301,6 +313,7 @@ def _graph_cut_kernel(w_ref, x_ref, ok_ref, best_ref, idx_ref, acc_ref):
   v = 1.0 - 2.0 * x
 
   part = jax.lax.dot_general(w, v, (((1,), (1,)), ((), ())),
+                             precision=DOT_PRECISION,
                              preferred_element_type=jnp.float32)  # (BM, 1)
 
   @pl.when((i == 0) & (j == 0))

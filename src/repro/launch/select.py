@@ -2,8 +2,10 @@
 
     PYTHONPATH=src python -m repro.launch.select --n 100000 --k 128 --mesh 8
 
-With --mesh N the ground set is sharded over N forced host devices and the
-production shard_map path runs (greedi_sharded_fast, or the generic
+With --mesh N the ground set is sharded over the first N devices of the
+process (on the CPU backend the flag below forces N host devices; on an
+accelerator the mesh needs N real chips) and the production shard_map path
+runs (greedi_sharded_fast, or the generic
 greedi_sharded with --no-fast); without it the reference implementation is
 used.  Any --n works on a mesh: non-divisible ground sets are padded with
 masked hole rows.  Both paths return *global document indices*, honor
@@ -32,7 +34,8 @@ import time
 
 def _force_host_devices(n: int) -> None:
   """Append the forced-device-count flag to XLA_FLAGS (setdefault would
-  silently drop it when XLA_FLAGS is already set for other reasons)."""
+  silently drop it when XLA_FLAGS is already set for other reasons).  Only
+  the CPU backend reads it; accelerator meshes use real devices."""
   flag = f"--xla_force_host_platform_device_count={n}"
   existing = os.environ.get("XLA_FLAGS", "")
   if "--xla_force_host_platform_device_count" not in existing:
@@ -88,8 +91,8 @@ def main() -> None:
   ap.add_argument("--kappa", type=int, default=None)
   ap.add_argument("--m", type=int, default=8, help="logical partitions "
                   "(reference path)")
-  ap.add_argument("--mesh", type=int, default=0, help="forced host devices "
-                  "for the sharded path")
+  ap.add_argument("--mesh", type=int, default=0, help="devices of the "
+                  "sharded path (forced host devices on CPU)")
   ap.add_argument("--kernel", default="linear", choices=["linear", "rbf"])
   ap.add_argument("--backend", default=None,
                   choices=["pallas", "ref", "auto"],
@@ -156,6 +159,13 @@ def main() -> None:
   import numpy as np
 
   from repro import obs
+  from repro.util import compile_cache
+
+  compile_cache()
+  if args.mesh and len(jax.devices()) < args.mesh:
+    raise SystemExit(
+        f"[select] --mesh {args.mesh} needs {args.mesh} devices; the "
+        f"{jax.default_backend()} backend has {len(jax.devices())}")
   from repro.data.pipeline import EmbeddedCorpus
   from repro.data.selection import (coverage_ratio, greedi_select_indices,
                                     greedi_select_indices_sharded)

@@ -24,7 +24,7 @@ layer splits that into two pieces:
     times.
 
 Per epoch the service draws a fresh uniform partition
-(``core/partition.repartition`` -- Barbosa-style re-randomization, which
+(``core/partition.partition_perm`` -- Barbosa-style re-randomization, which
 preserves the distributed approximation guarantee across repeated runs) and
 runs ``greedi_sharded(mode="lazy")``.  With a maintained bound table, round
 1 is WARM-STARTED: the sum-form table divided by each shard's live count
@@ -70,7 +70,8 @@ from repro import obs
 from repro.core import greedi as GD
 from repro.core import objectives as O
 from repro.core.objectives import NEG
-from repro.core.partition import partition_gids, repartition, shard_live_counts
+from repro.core.partition import (partition_perm, permute_rows,
+                                  shard_live_counts)
 from repro.service.heartbeat import HeartbeatBoard
 from repro.service.store import CorpusStore
 
@@ -260,7 +261,7 @@ class SelectionService:
     """Build the ONE epoch function.  Shapes (capacity) are read off the
     runtime arguments, so capacity growth re-traces this same jit object --
     that is the O(log n) recompile budget, counted by ``retrace_count``."""
-    d, m = self._d, self._m
+    m = self._m
     obj = self._objective
     axis_names = self._axis_names
     warm, maintainer = self._warm, self._maintainer
@@ -272,10 +273,13 @@ class SelectionService:
       r_part, r_run = jax.random.split(rng)
       # fresh uniform partition every epoch (Barbosa-style re-randomization);
       # cap is a mesh multiple, so the perm has no padding of its own and
-      # the only holes are the block's gid = -1 rows
-      parts, _, perm = repartition(r_part, feats, m)
-      feats_sh = parts.reshape(cap, d)
-      gids_sh = partition_gids(perm, gids)
+      # the only holes are the block's gid = -1 rows (zero features, which
+      # is what the move fills them with).  Live rows move shard to shard
+      # (all_to_all), never through a full copy on every device.
+      perm = partition_perm(r_part, cap)
+      feats_sh, gids_sh, ub_sh = permute_rows(
+          (feats, gids, ubound), (0, -1, 0), perm, gids >= 0, mesh=self.mesh,
+          axis_names=axis_names)
       wb = None
       if warm:
         valid_sh = gids_sh >= 0
@@ -283,8 +287,7 @@ class SelectionService:
         # (holes sort to NEG); the divide-by-live-count transform is the
         # maintainer's epoch_bounds
         nv = shard_live_counts(valid_sh, m)
-        wb = jnp.where(valid_sh, ubound[jnp.maximum(perm.reshape(cap), 0)],
-                       NEG)
+        wb = jnp.where(valid_sh, ub_sh, NEG)
         wb = maintainer.epoch_bounds(wb, jnp.repeat(nv, npp))
         # slack keeps the bounds valid under f32 summation-order noise
         wb = wb * (1.0 + _BOUND_SLACK_REL) + _BOUND_SLACK_ABS

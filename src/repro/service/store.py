@@ -612,7 +612,7 @@ class CorpusStore:
 
     def merge(sgid, sgain, sfeat, kq, excl, seed):
       self._query_trace_count += 1  # python side effect: counts traces
-      return merge_one(sgid, sgain, sfeat, kq, excl, seed)
+      return self._replicated(merge_one)(sgid, sgain, sfeat, kq, excl, seed)
 
     # raw bodies kept for the analyzer (repro.analysis.entries) and for the
     # batched compile (the batched merge is the SAME body vmapped over the
@@ -635,12 +635,21 @@ class CorpusStore:
 
     def merge_batch(sgid, sgain, sfeat, kq, excl, seeds):
       self._query_batch_trace_count += 1  # python side effect: trace count
-      return jax.vmap(merge_one, in_axes=(None, None, None, 0, 0, 0))(
-          sgid, sgain, sfeat, kq, excl, seeds)
+      return self._replicated(jax.vmap(
+          merge_one, in_axes=(None, None, None, 0, 0, 0)))(
+              sgid, sgain, sfeat, kq, excl, seeds)
 
     # raw body kept for the analyzer (repro.analysis.entries)
     self._query_batch_raw = merge_batch
     self._query_batch_fn = jax.jit(merge_batch)
+
+  def _replicated(self, fn):
+    """Run a sieve merge whole on every device of the mesh: the standing
+    state (m * T * k rows) is gathered to each one.  Pallas kernels cannot
+    be partitioned by GSPMD, so on a mesh of several chips the merge's
+    ``pairwise`` calls must sit inside a shard_map."""
+    return _shard_map(fn, mesh=self._mesh, in_specs=(P(),) * 6,
+                      out_specs=(P(), P()))
 
   def _full_excl(self, b: int | None = None) -> np.ndarray:
     """All -1 exclusion list(s): the 'no tenant filter' argument."""

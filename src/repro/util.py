@@ -10,6 +10,8 @@ rolled loops.
 from __future__ import annotations
 
 import contextlib
+import os
+import pathlib
 import threading
 from typing import Any, Callable
 
@@ -53,29 +55,39 @@ def fori(lo: int, hi: int, body: Callable, init):
 
 
 # ---------------------------------------------------------------------------
-# jax version compatibility (mesh construction + shard_map)
+# meshes, shard_map and the compilation cache
 # ---------------------------------------------------------------------------
 
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[2]
 
-def make_mesh(axis_shapes, axis_names):
-  """jax.make_mesh with Auto axis types where the installed jax supports
-  them (jax.sharding.AxisType landed after 0.4.x), plain mesh otherwise."""
-  axis_type = getattr(jax.sharding, "AxisType", None)
-  if axis_type is not None:
-    try:
-      return jax.make_mesh(axis_shapes, axis_names,
-                           axis_types=(axis_type.Auto,) * len(axis_names))
-    except TypeError:
-      pass
-  return jax.make_mesh(axis_shapes, axis_names)
+
+def make_mesh(axis_shapes, axis_names, devices=None):
+  """jax.make_mesh over the first prod(axis_shapes) of ``devices`` (default:
+  all devices of the process) with Auto axis types: the sharded paths here
+  place data through explicit shard_map specs, not sharding-in-types."""
+  return jax.make_mesh(axis_shapes, axis_names,
+                       axis_types=(jax.sharding.AxisType.Auto,)
+                       * len(axis_names), devices=devices)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-  """shard_map with replication/VMA checking off, across jax versions
-  (jax.shard_map + check_vma new-style; jax.experimental + check_rep old)."""
-  if hasattr(jax, "shard_map"):
-    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-  from jax.experimental.shard_map import shard_map as _shard_map
-  return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=False)
+  """jax.shard_map with varying-manual-axes checking off."""
+  return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                       check_vma=False)
+
+
+def compile_cache() -> str:
+  """Turn on JAX's persistent compilation cache; return its directory.
+
+  ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting: it is left
+  to JAX and no other directory is set.  Otherwise the cache lives at the
+  fixed path ``<repo>/.jax_cache``: the directory is part of what a later
+  process must find again, so it never carries a temporary name, a pid or a
+  time.
+  """
+  env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+  if env:
+    return env
+  path = str(REPO_ROOT / ".jax_cache")
+  jax.config.update("jax_compilation_cache_dir", path)
+  return path

@@ -112,3 +112,33 @@ def test_graph_cut_padded_universe_rows_no_gain():
     st = obj.update(st, jnp.eye(n + n_pad)[2])
     g = obj.gains(st, jnp.eye(n + n_pad))
     np.testing.assert_allclose(np.asarray(g[n:]), 0.0, atol=1e-6)
+
+
+def test_permute_rows_matches_gather_without_moving_holes(subrun):
+  """The epoch's shard-to-shard row move equals a plain gather by the
+  partition permutation, bit for bit, with hole rows left at their fill --
+  both in one all_to_all round and when the small shards need several."""
+  out = subrun("""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core.partition import partition_perm, permute_rows
+from repro.util import make_mesh
+mesh = make_mesh((4,), ("data",))
+sh = NamedSharding(mesh, P("data"))
+for n in (32, 16384):
+  x = jax.device_put(jnp.arange(n * 3, dtype=jnp.float32).reshape(n, 3), sh)
+  g = jax.device_put(jnp.arange(n, dtype=jnp.int32) - 5, sh)
+  v = jax.device_put((jnp.arange(n) % 3) != 0, sh)
+  perm = partition_perm(jax.random.PRNGKey(n), n)
+  a, b = jax.jit(lambda x, g, p, v: permute_rows(
+      (x, g), (0, -1), p, v, mesh=mesh, axis_names=("data",)))(x, g, perm, v)
+  pn = np.asarray(perm)
+  keep = np.asarray(v)[pn]
+  np.testing.assert_array_equal(
+      np.asarray(a), np.where(keep[:, None], np.asarray(x)[pn], 0))
+  np.testing.assert_array_equal(
+      np.asarray(b), np.where(keep, np.asarray(g)[pn], -1))
+  assert a.sharding.spec == P("data")
+print("PERMUTE_OK")
+""", n_devices=4)
+  assert "PERMUTE_OK" in out
