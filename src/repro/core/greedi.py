@@ -748,48 +748,53 @@ def greedi_sharded(feats: Array, *, mesh, kappa: int, k_final: int,
     n_live = jnp.sum(evalw.astype(jnp.float32))
 
     # ---- round 1: local greedy on the shard's live partition rows --------
-    st0 = objective.init(local_feats, evalw)
-    r1 = greedy(objective, st0, local_feats, kappa, cand_mask=local_valid,
-                rng=key, mode=mode,
-                warm_bounds=local_wb if use_warm else None)
-    sel = r1.feats                                   # (kappa, d)
-    valid = (r1.idx >= 0) & my_keep
-    gsel = jnp.where(r1.idx >= 0, local_gids[jnp.maximum(r1.idx, 0)], -1)
+    with jax.named_scope("greedi.round1"):
+      st0 = objective.init(local_feats, evalw)
+      r1 = greedy(objective, st0, local_feats, kappa, cand_mask=local_valid,
+                  rng=key, mode=mode,
+                  warm_bounds=local_wb if use_warm else None)
+      sel = r1.feats                                   # (kappa, d)
+      valid = (r1.idx >= 0) & my_keep
+      gsel = jnp.where(r1.idx >= 0, local_gids[jnp.maximum(r1.idx, 0)], -1)
 
     if merge == "flat":
       # ---- merge: one all_gather of the candidate blocks -----------------
-      B = jax.lax.all_gather(sel, axis_names)          # (m, kappa, d)
-      Bvalid = jax.lax.all_gather(valid, axis_names)   # (m, kappa)
-      Bgids = jax.lax.all_gather(gsel, axis_names)     # (m, kappa)
-      Bflat = B.reshape(m * kappa, d)
-      Bmask = Bvalid.reshape(m * kappa)
-      Bgflat = Bgids.reshape(m * kappa)
+      with jax.named_scope("greedi.merge"):
+        B = jax.lax.all_gather(sel, axis_names)          # (m, kappa, d)
+        Bvalid = jax.lax.all_gather(valid, axis_names)   # (m, kappa)
+        Bgids = jax.lax.all_gather(gsel, axis_names)     # (m, kappa)
+        Bflat = B.reshape(m * kappa, d)
+        Bmask = Bvalid.reshape(m * kappa)
+        Bgflat = Bgids.reshape(m * kappa)
 
-      # evaluation weight of this shard: full-set eval or the Thm-10 U
-      # subset held by the first ALIVE shard, and zero for dead machines --
-      # their data carries no evaluation mass
-      u_holder = jnp.argmax(keep)                      # first alive shard
-      w = jnp.where(u_subset_eval, (me == u_holder).astype(jnp.float32), 1.0)
-      w = w * my_keep.astype(jnp.float32)
-      denom = _psum(n_live * w, axis_names)
-      denom = jnp.maximum(denom, 1.0)
+        # evaluation weight of this shard: full-set eval or the Thm-10 U
+        # subset held by the first ALIVE shard, and zero for dead machines
+        # -- their data carries no evaluation mass
+        u_holder = jnp.argmax(keep)                      # first alive shard
+        w = jnp.where(u_subset_eval, (me == u_holder).astype(jnp.float32),
+                      1.0)
+        w = w * my_keep.astype(jnp.float32)
+        denom = _psum(n_live * w, axis_names)
+        denom = jnp.maximum(denom, 1.0)
 
-      # ---- A_max: value of each machine's solution under final eval ------
-      def value_of(sel_i, valid_i):
-        st = set_value_feats(objective, objective.init(local_feats, evalw),
-                             sel_i, valid_i)
-        # local mean * local live count -> psum-able sum
-        return objective.value(st) * n_live * w
-      part_vals = jax.vmap(value_of)(B, Bvalid)        # (m,)
-      stage1_vals = _psum(part_vals, axis_names) / denom
-      stage1_vals = jnp.where(keep, stage1_vals, -jnp.inf)
-      best_i = jnp.argmax(stage1_vals)
+        # ---- A_max: value of each machine's solution under final eval ----
+        def value_of(sel_i, valid_i):
+          st = set_value_feats(objective, objective.init(local_feats, evalw),
+                               sel_i, valid_i)
+          # local mean * local live count -> psum-able sum
+          return objective.value(st) * n_live * w
+        part_vals = jax.vmap(value_of)(B, Bvalid)        # (m,)
+        stage1_vals = _psum(part_vals, axis_names) / denom
+        stage1_vals = jnp.where(keep, stage1_vals, -jnp.inf)
+        best_i = jnp.argmax(stage1_vals)
 
       # ---- round 2: distributed greedy over B ----------------------------
-      engine = _objective_engine(objective, local_feats, Bflat, Bmask,
-                                 Bgflat, eval_mask=evalw)
-      merged_feats, merged_valid, merged_gids, v_merged = _dist_greedy_core(
-          engine, k_final, axis_names, w, denom, feats.dtype)
+      with jax.named_scope("greedi.round2"):
+        engine = _objective_engine(objective, local_feats, Bflat, Bmask,
+                                   Bgflat, eval_mask=evalw)
+        merged_feats, merged_valid, merged_gids, v_merged = \
+            _dist_greedy_core(engine, k_final, axis_names, w, denom,
+                              feats.dtype)
     else:
       # ---- merge: accumulation tree, innermost axis up -------------------
       # Level l all_gathers the subtree representatives' blocks over ONE
@@ -808,53 +813,57 @@ def greedi_sharded(feats: Array, *, mesh, kappa: int, k_final: int,
         c_l = mesh.shape[ax]
         s_l = _mesh_size(mesh, sub_axes)
         kprev = Q.shape[0]
-        B = jax.lax.all_gather(Q, ax)                  # (c_l, kprev, d)
-        Bvalid = jax.lax.all_gather(Qv, ax)
-        Bgids = jax.lax.all_gather(Qg, ax)
-        Bflat = B.reshape(c_l * kprev, d)
-        Bmask = Bvalid.reshape(c_l * kprev)
-        Bgflat = Bgids.reshape(c_l * kprev)
-        # Thm-10 holder *per subtree*: the first alive shard among the s_l
-        # consecutive combined indices this level's psums span, re-elected
-        # from the liveness mask at every level -- a dead interior node's
-        # subtree keeps merging under its next alive member's U subset
-        base = (me // s_l) * s_l
-        sub_keep = jax.lax.dynamic_slice(keep, (base,), (s_l,))
-        u_holder = base + jnp.argmax(sub_keep)
-        w = jnp.where(u_subset_eval, (me == u_holder).astype(jnp.float32),
-                      1.0)
-        w = w * my_keep.astype(jnp.float32)
-        denom = jnp.maximum(_psum(n_live * w, sub_axes), 1.0)
-        if root:
-          # A_max over the root's children (== per-machine when b = m);
-          # a child is alive iff ANY shard of its subtree is
-          def value_of(sel_i, valid_i):
-            st = set_value_feats(objective,
-                                 objective.init(local_feats, evalw),
-                                 sel_i, valid_i)
-            return objective.value(st) * n_live * w
-          part_vals = jax.vmap(value_of)(B, Bvalid)    # (c_l,)
-          stage1_vals = _psum(part_vals, sub_axes) / denom
-          child_keep = jnp.any(keep.reshape(c_l, s_l // c_l), axis=1)
-          stage1_vals = jnp.where(child_keep, stage1_vals, -jnp.inf)
-          best_i = jnp.argmax(stage1_vals)
-        engine = _objective_engine(objective, local_feats, Bflat, Bmask,
-                                   Bgflat, eval_mask=evalw)
-        Q, Qv, Qg, v_merged = _dist_greedy_core(
-            engine, k_final if root else kappa, sub_axes, w, denom,
-            feats.dtype)
+        with jax.named_scope("greedi.merge"):
+          B = jax.lax.all_gather(Q, ax)                  # (c_l, kprev, d)
+          Bvalid = jax.lax.all_gather(Qv, ax)
+          Bgids = jax.lax.all_gather(Qg, ax)
+          Bflat = B.reshape(c_l * kprev, d)
+          Bmask = Bvalid.reshape(c_l * kprev)
+          Bgflat = Bgids.reshape(c_l * kprev)
+          # Thm-10 holder *per subtree*: the first alive shard among the
+          # s_l consecutive combined indices this level's psums span,
+          # re-elected from the liveness mask at every level -- a dead
+          # interior node's subtree keeps merging under its next alive
+          # member's U subset
+          base = (me // s_l) * s_l
+          sub_keep = jax.lax.dynamic_slice(keep, (base,), (s_l,))
+          u_holder = base + jnp.argmax(sub_keep)
+          w = jnp.where(u_subset_eval, (me == u_holder).astype(jnp.float32),
+                        1.0)
+          w = w * my_keep.astype(jnp.float32)
+          denom = jnp.maximum(_psum(n_live * w, sub_axes), 1.0)
+          if root:
+            # A_max over the root's children (== per-machine when b = m);
+            # a child is alive iff ANY shard of its subtree is
+            def value_of(sel_i, valid_i):
+              st = set_value_feats(objective,
+                                   objective.init(local_feats, evalw),
+                                   sel_i, valid_i)
+              return objective.value(st) * n_live * w
+            part_vals = jax.vmap(value_of)(B, Bvalid)    # (c_l,)
+            stage1_vals = _psum(part_vals, sub_axes) / denom
+            child_keep = jnp.any(keep.reshape(c_l, s_l // c_l), axis=1)
+            stage1_vals = jnp.where(child_keep, stage1_vals, -jnp.inf)
+            best_i = jnp.argmax(stage1_vals)
+        with jax.named_scope("greedi.round2"):
+          engine = _objective_engine(objective, local_feats, Bflat, Bmask,
+                                     Bgflat, eval_mask=evalw)
+          Q, Qv, Qg, v_merged = _dist_greedy_core(
+              engine, k_final if root else kappa, sub_axes, w, denom,
+              feats.dtype)
       merged_feats, merged_valid, merged_gids = Q, Qv, Qg
 
     # ---- pick the better of A_B and A_max --------------------------------
-    v_best_single = stage1_vals[best_i]
-    use_merged = v_merged >= v_best_single
-    sel_feats = jnp.where(use_merged, merged_feats,
-                          _take_k(B[best_i], k_final, 0.0))
-    sel_valid = jnp.where(use_merged, merged_valid,
-                          _take_k(Bvalid[best_i], k_final, False))
-    sel_gids = jnp.where(use_merged, merged_gids,
-                         _take_k(Bgids[best_i], k_final, -1))
-    value = jnp.maximum(v_merged, v_best_single)
+    with jax.named_scope("greedi.round2"):
+      v_best_single = stage1_vals[best_i]
+      use_merged = v_merged >= v_best_single
+      sel_feats = jnp.where(use_merged, merged_feats,
+                            _take_k(B[best_i], k_final, 0.0))
+      sel_valid = jnp.where(use_merged, merged_valid,
+                            _take_k(Bvalid[best_i], k_final, False))
+      sel_gids = jnp.where(use_merged, merged_gids,
+                           _take_k(Bgids[best_i], k_final, -1))
+      value = jnp.maximum(v_merged, v_best_single)
     # per-machine lazy rescan counts: scalar -> (m,) replicated, ordered by
     # the same combined shard index as every other per-machine output
     rescans = jax.lax.all_gather(r1.rescans.astype(jnp.int32), axis_names)

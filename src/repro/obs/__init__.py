@@ -15,8 +15,9 @@ Design contract (docs/observability.md has the full catalog):
     compiled fns -- the traced program is identical with obs on or off, so
     instrumentation can never change ``retrace_count`` /
     ``query_trace_count`` / ``query_batch_trace_count``.  Enablement gates
-    only the host side: device->host reads of those diagnostics, JSONL
-    span emission, and profiler annotations.
+    only the host side: device->host reads of those diagnostics and JSONL
+    span emission.  Spans always open a profiler annotation (inactive, and
+    sub-microsecond, when no profiler session is running).
   * Registry updates are always on (nanoseconds of locked dict math), so
     bench ``--json`` collections carry counter context even in the
     "disabled" configuration the regression gate times.

@@ -5,10 +5,9 @@ updates are plain lock-protected python -- an ``inc``/``set``/``observe`` is
 a dict lookup plus an int/float update, nanoseconds-scale, so instrumented
 call sites leave them unconditionally on.  What observability *enablement*
 (``obs.enable()``) gates is everything with a real cost: device->host reads
-of the device-fed diagnostics, JSONL span emission, and
-``jax.profiler.TraceAnnotation`` wrapping (see trace.py).  That split is
-what keeps the disabled-mode overhead near zero while bench/CI collections
-can still snapshot the cheap counters.
+of the device-fed diagnostics and JSONL span emission (see trace.py).  That
+split is what keeps the disabled-mode overhead near zero while bench/CI
+collections can still snapshot the cheap counters.
 
 Label sets are passed as keyword arguments and become part of the series
 identity, Prometheus-style::
@@ -96,14 +95,21 @@ class Histogram:
     self._series: dict[tuple, tuple[list[int], float, int]] = {}
 
   def observe(self, value: float, **labels) -> None:
+    self.observe_many((value,), **labels)
+
+  def observe_many(self, values: Iterable[float], **labels) -> None:
+    """Observe several values of one label set under one lock hold."""
     key = _label_key(labels)
     with self._lock:
       counts, total, n = self._series.get(
           key, ([0] * len(self.buckets), 0.0, 0))
-      for i, b in enumerate(self.buckets):
-        if value <= b:
-          counts[i] += 1
-      self._series[key] = (counts, total + float(value), n + 1)
+      for value in values:
+        for i, b in enumerate(self.buckets):
+          if value <= b:
+            counts[i] += 1
+        total += float(value)
+        n += 1
+      self._series[key] = (counts, total, n)
 
   def get(self, **labels) -> dict:
     """{"count", "sum", "buckets": {le: cumulative}} for one label set."""

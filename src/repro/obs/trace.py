@@ -1,16 +1,24 @@
 """Structured trace spans: the single timing source of the service layer.
 
-``span("service.epoch", epoch=3)`` is a context manager that ALWAYS measures
-wall clock (``Span.wall_s`` after exit -- the service's stats dataclasses
-consume it, so spans replace every ad-hoc ``time.perf_counter()`` pair in
-service.py / batching.py even with observability disabled).  Only when
-observability is *enabled* does a span additionally
+``span("service.epoch", epoch=3)`` is a context manager that ALWAYS
 
-  * append one JSONL record to the configured trace sink
-    (``{"name", "ts", "dur_s", "pid", "tid", "attrs"}`` -- monotonic
-    ``ts`` of span entry, so records order and subtract cleanly), and
-  * wrap the body in ``jax.profiler.TraceAnnotation`` so the span lands in
-    perfetto profiles next to the XLA ops it encloses.
+  * measures wall clock (``Span.wall_s`` after exit -- the service's stats
+    dataclasses consume it, so spans replace every ad-hoc
+    ``time.perf_counter()`` pair in service.py / batching.py), and
+  * opens a ``jax.profiler.TraceAnnotation`` of the span's name, so the span
+    lands in a profiler trace next to the device ops it encloses.  With no
+    profiler session running the annotation is inactive and costs well
+    under a microsecond (docs/observability.md).
+
+Only when observability is *enabled* does a span additionally append one
+JSONL record to the configured trace sink::
+
+    {"name", "ts", "dur_s", "pid", "tid", "parent", "attrs"}
+
+``ts`` is the span's start on the clock the profiler stamps host events
+with (the realtime clock, in seconds since the epoch), so a record lines up
+with its annotation in the ``.xplane.pb`` host plane; ``parent`` is the
+name of the enclosing span on the same thread (None at the top).
 
 Enable/disable is process-global::
 
@@ -19,11 +27,9 @@ Enable/disable is process-global::
         ...
     stats.wall_s = sp.wall_s
 
-Disabled-mode cost is two ``perf_counter()`` calls and a handful of python
-attribute reads -- no file IO, no profiler hooks, no device access.  A span
-also never touches the device: callers that need device-synced timing keep
-their own ``jax.block_until_ready`` inside the span, exactly as the service
-epoch does.
+A span never touches the device: callers that need device-synced timing
+keep their own ``jax.block_until_ready`` inside the span, exactly as the
+service epoch does.
 
 ``add(**attrs)`` attaches attributes discovered mid-span (e.g. the batch
 occupancy a drain only knows after collecting); they merge into the JSONL
@@ -37,11 +43,14 @@ import threading
 import time
 from typing import IO
 
+import jax.profiler
+
 _STATE_LOCK = threading.Lock()
 _ENABLED = False
 _TRACE_PATH: str | None = None
 _TRACE_FILE: IO | None = None
 _TRACE_WRITE_LOCK = threading.Lock()
+_LOCAL = threading.local()   # .stack: names of this thread's open spans
 
 
 def enable(trace_out: str | None = None) -> None:
@@ -86,18 +95,28 @@ def _emit(record: dict) -> None:
     _TRACE_FILE.write(json.dumps(record, sort_keys=True) + "\n")
 
 
+def _stack() -> list:
+  stack = getattr(_LOCAL, "stack", None)
+  if stack is None:
+    stack = _LOCAL.stack = []
+  return stack
+
+
 class Span:
   """One timed region; see module docstring.  Not reentrant."""
 
-  __slots__ = ("name", "attrs", "wall_s", "_t0", "_ann", "_emitting")
+  __slots__ = ("name", "attrs", "wall_s", "_t0", "_ts", "_ann", "_emitting",
+               "_parent")
 
   def __init__(self, name: str, attrs: dict):
     self.name = name
     self.attrs = attrs
     self.wall_s = 0.0
     self._t0 = 0.0
+    self._ts = 0.0
     self._ann = None
     self._emitting = False
+    self._parent = None
 
   def add(self, **attrs) -> None:
     """Attach attributes discovered mid-span (merged into the record)."""
@@ -105,25 +124,25 @@ class Span:
 
   def __enter__(self) -> "Span":
     self._emitting = _ENABLED  # latch: enablement mid-span doesn't half-emit
+    stack = _stack()
+    self._parent = stack[-1] if stack else None
+    stack.append(self.name)
     if self._emitting:
-      try:
-        import jax
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
-      except Exception:
-        self._ann = None  # profiling unavailable; JSONL still emits
+      self._ts = time.time_ns() * 1e-9
+    self._ann = jax.profiler.TraceAnnotation(self.name)
+    self._ann.__enter__()
     self._t0 = time.perf_counter()
     return self
 
   def __exit__(self, *exc) -> None:
     self.wall_s = time.perf_counter() - self._t0
-    if self._ann is not None:
-      self._ann.__exit__(*exc)
-      self._ann = None
+    self._ann.__exit__(*exc)
+    self._ann = None
+    _stack().pop()
     if self._emitting:
-      _emit({"name": self.name, "ts": self._t0, "dur_s": self.wall_s,
-             "pid": os.getpid(),
-             "tid": threading.get_ident(), "attrs": self.attrs})
+      _emit({"name": self.name, "ts": self._ts, "dur_s": self.wall_s,
+             "pid": os.getpid(), "tid": threading.get_ident(),
+             "parent": self._parent, "attrs": self.attrs})
 
 
 def span(name: str, **attrs) -> Span:

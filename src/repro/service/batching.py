@@ -10,9 +10,12 @@ through ONE ``query_batch`` call whenever
 
   * ``max_batch`` requests have accumulated (the store's compiled query
     tile by default -- a full tile is the highest-throughput drain), or
-  * ``max_delay_s`` has passed since the oldest pending request (the
-    latency SLO knob: no request ever waits longer than the deadline plus
-    one drain).
+  * ``max_delay_s`` has passed since the worker, free again, found a
+    request pending (the latency SLO knob).  The deadline starts when the
+    worker wakes, not when the oldest request was submitted, so a request
+    that arrives during a drain waits out the rest of that drain and then
+    the whole deadline; ``repro_batcher_queue_wait_seconds`` records what
+    each request waited before its drain started.
 
 Every request in a drained batch observes the same wall clock (that is what
 ``QueryResult.wall_s`` reports), so the p50/p95 latency surface of the
@@ -56,8 +59,9 @@ class QueryBatcher:
     service: the ``SelectionService`` to drain through.
     max_batch: drain threshold; None = the store's compiled query tile
       (bigger values still work -- the store chunks by tile).
-    max_delay_s: the latency SLO knob -- maximum time the oldest pending
-      request waits before a (possibly partial) drain.
+    max_delay_s: the latency SLO knob -- how long the free worker waits
+      for a full tile, once a request is pending, before a (possibly
+      partial) drain.
     tier: forwarded to ``query_batch`` ("sieve" | "exact").
   """
 
@@ -70,7 +74,8 @@ class QueryBatcher:
     self._max_delay = float(max_delay_s)
     self._tier = tier
     self._cv = threading.Condition()
-    self._pending: list[tuple[QueryRequest, Future]] = []
+    # (request, future, submit time on the perf_counter clock)
+    self._pending: list[tuple[QueryRequest, Future, float]] = []
     self._closed = False
     self.stats = BatcherStats()
     self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -85,48 +90,56 @@ class QueryBatcher:
     with self._cv:
       if self._closed:
         raise RuntimeError("QueryBatcher is closed")
-      self._pending.append((req, fut))
+      self._pending.append((req, fut, time.perf_counter()))
       self.stats.submitted += 1
       self._cv.notify()
     return fut
 
   def _loop(self) -> None:
+    reg = obs.REGISTRY
     while True:
       with self._cv:
         while not self._pending and not self._closed:
           self._cv.wait()
         if not self._pending and self._closed:
           return
-        # the deadline runs from the OLDEST pending request: wait for a
-        # full tile, but never past the SLO
-        deadline = time.perf_counter() + self._max_delay
-        while len(self._pending) < self._max_batch and not self._closed:
-          left = deadline - time.perf_counter()
-          if left <= 0:
-            break
-          self._cv.wait(timeout=left)
+        # the deadline runs from now, when the worker found a request
+        # pending: wait for a full tile, but never past the SLO
+        with obs.span("batcher.wait"):
+          deadline = time.perf_counter() + self._max_delay
+          while len(self._pending) < self._max_batch and not self._closed:
+            left = deadline - time.perf_counter()
+            if left <= 0:
+              break
+            self._cv.wait(timeout=left)
         batch = self._pending[:self._max_batch]
         del self._pending[:self._max_batch]
       with obs.span("batcher.drain", tier=self._tier,
                     occupancy=len(batch)) as sp:
+        t0 = time.perf_counter()
+        reg.histogram("repro_batcher_queue_wait_seconds",
+                      "per request: submit to the start of the drain it "
+                      "rides in").observe_many([t0 - t for _, _, t in batch])
         try:
-          results = self._svc.query_batch([r for r, _ in batch],
+          results = self._svc.query_batch([r for r, _, _ in batch],
                                           tier=self._tier)
-          for (_, fut), res in zip(batch, results):
-            fut.set_result(res)
+          error = None
         except Exception as e:  # a bad request poisons only its own batch
-          for _, fut in batch:
-            fut.set_exception(e)
-      self.stats.batches += 1
-      self.stats.served += len(batch)
-      self.stats.max_occupancy = max(self.stats.max_occupancy, len(batch))
-      reg = obs.REGISTRY
-      reg.counter("repro_batcher_requests_total",
-                  "requests drained by the micro-batcher").inc(len(batch))
-      reg.counter("repro_batcher_batches_total",
-                  "micro-batch drains").inc()
-      reg.gauge("repro_batcher_occupancy",
-                "requests in the last drained batch").set(len(batch))
+          error = e
+        with obs.span("batcher.resolve"):
+          for i, (_, fut, _) in enumerate(batch):
+            if error is None:
+              fut.set_result(results[i])
+            else:
+              fut.set_exception(error)
+          self.stats.batches += 1
+          self.stats.served += len(batch)
+          self.stats.max_occupancy = max(self.stats.max_occupancy,
+                                         len(batch))
+          reg.counter("repro_batcher_requests_total",
+                      "requests drained by the micro-batcher").inc(len(batch))
+          reg.counter("repro_batcher_batches_total",
+                      "micro-batch drains").inc()
       reg.histogram("repro_batcher_drain_wall_seconds",
                     "wall clock of one drain (the request latency "
                     "surface)").observe(sp.wall_s)

@@ -276,21 +276,23 @@ class SelectionService:
       # the only holes are the block's gid = -1 rows (zero features, which
       # is what the move fills them with).  Live rows move shard to shard
       # (all_to_all), never through a full copy on every device.
-      perm = partition_perm(r_part, cap)
-      feats_sh, gids_sh, ub_sh = permute_rows(
-          (feats, gids, ubound), (0, -1, 0), perm, gids >= 0, mesh=self.mesh,
-          axis_names=axis_names)
+      with jax.named_scope("greedi.partition"):
+        perm = partition_perm(r_part, cap)
+        feats_sh, gids_sh, ub_sh = permute_rows(
+            (feats, gids, ubound), (0, -1, 0), perm, gids >= 0,
+            mesh=self.mesh, axis_names=axis_names)
       wb = None
       if warm:
-        valid_sh = gids_sh >= 0
-        # sum-form corpus table -> per-shard mean-form empty-set bounds
-        # (holes sort to NEG); the divide-by-live-count transform is the
-        # maintainer's epoch_bounds
-        nv = shard_live_counts(valid_sh, m)
-        wb = jnp.where(valid_sh, ub_sh, NEG)
-        wb = maintainer.epoch_bounds(wb, jnp.repeat(nv, npp))
-        # slack keeps the bounds valid under f32 summation-order noise
-        wb = wb * (1.0 + _BOUND_SLACK_REL) + _BOUND_SLACK_ABS
+        with jax.named_scope("greedi.round1"):
+          valid_sh = gids_sh >= 0
+          # sum-form corpus table -> per-shard mean-form empty-set bounds
+          # (holes sort to NEG); the divide-by-live-count transform is the
+          # maintainer's epoch_bounds
+          nv = shard_live_counts(valid_sh, m)
+          wb = jnp.where(valid_sh, ub_sh, NEG)
+          wb = maintainer.epoch_bounds(wb, jnp.repeat(nv, npp))
+          # slack keeps the bounds valid under f32 summation-order noise
+          wb = wb * (1.0 + _BOUND_SLACK_REL) + _BOUND_SLACK_ABS
       result = GD.greedi_sharded(
           feats_sh, mesh=self.mesh, kappa=self._kappa,
           k_final=self._k_final, objective=obj, axis_names=axis_names,
@@ -557,20 +559,21 @@ class SelectionService:
           deadline, rng)
       jax.block_until_ready((r, eval_mass, merge_rows))
     wall = sp.wall_s
-    sv = np.asarray(r.sel_valid)
-    sel_all = np.asarray(r.sel_gids)
-    feats_all = np.asarray(r.sel_feats)
-    d2h = sv.nbytes + sel_all.nbytes + feats_all.nbytes
-    sel = sel_all[sv]
-    sel_feats = feats_all[sv]
-    keep = sel >= 0
-    sel, sel_feats = sel[keep], sel_feats[keep]
-    stats = EpochStats(epoch=self._epoch_idx, n_live=self.store.n_docs,
-                       capacity=self.store.capacity, value=float(r.value),
-                       alive=np.asarray(r.alive), warm=warm_eff,
-                       wall_s=wall, retraces=self._trace_count)
-    self._feed_epoch_metrics(stats, r, eval_mass, merge_rows,
-                             h2d_bytes=h2d, d2h_bytes=d2h)
+    with obs.span("service.epoch_fetch"):
+      sv = np.asarray(r.sel_valid)
+      sel_all = np.asarray(r.sel_gids)
+      feats_all = np.asarray(r.sel_feats)
+      d2h = sv.nbytes + sel_all.nbytes + feats_all.nbytes
+      sel = sel_all[sv]
+      sel_feats = feats_all[sv]
+      keep = sel >= 0
+      sel, sel_feats = sel[keep], sel_feats[keep]
+      stats = EpochStats(epoch=self._epoch_idx, n_live=self.store.n_docs,
+                         capacity=self.store.capacity, value=float(r.value),
+                         alive=np.asarray(r.alive), warm=warm_eff,
+                         wall_s=wall, retraces=self._trace_count)
+      self._feed_epoch_metrics(stats, r, eval_mass, merge_rows,
+                               h2d_bytes=h2d, d2h_bytes=d2h)
     self._epoch_idx += 1
     result = EpochResult(sel, stats, r)
     # epoch output seeds the fresh sieve grid: queries between epochs start
@@ -627,7 +630,6 @@ class SelectionService:
     em = np.asarray(eval_mass)
     rescans = np.asarray(r.r1_rescans)
     rows = np.asarray(merge_rows)
-    row_bytes = self._d * np.dtype(self.store.feats.dtype).itemsize
     for i in range(em.shape[0]):
       reg.gauge("repro_epoch_eval_mass",
                 "per-shard live evaluation rows (device-fed)").set(
@@ -636,9 +638,6 @@ class SelectionService:
                 "per-shard peak merged-candidate rows gathered by the "
                 "epoch merge (device-fed; b*kappa tree vs m*kappa flat)"
                 ).set(int(rows[i]), shard=i)
-      reg.gauge("repro_merge_peak_bytes",
-                "per-shard peak merged-candidate bytes (rows * d * "
-                "itemsize)").set(int(rows[i]) * row_bytes, shard=i)
     reg.counter("repro_lazy_tile_rescans_total",
                 "round-1 lazy tiles rescanned (device-fed)").inc(
                     int(rescans.sum()))

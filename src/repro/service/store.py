@@ -334,32 +334,34 @@ class CorpusStore:
       # that land in its own slice (O(append_block) work per shard, no
       # collectives) -- the write pattern a global scatter on the sharded
       # block would otherwise turn into an O(capacity) GSPMD gather/scatter
-      me = _combined_index(ax, mesh)
-      pos = off + jnp.arange(ab, dtype=jnp.int32) - me * npp
-      mine = (rvalid > 0) & (pos >= 0) & (pos < npp)
-      widx = jnp.where(mine, pos, npp)   # out of local range -> dropped
-      lfeats = lfeats.at[widx].set(rows, mode="drop")
-      lgids = lgids.at[widx].set(rgids, mode="drop")
+      with jax.named_scope("store.write"):
+        me = _combined_index(ax, mesh)
+        pos = off + jnp.arange(ab, dtype=jnp.int32) - me * npp
+        mine = (rvalid > 0) & (pos >= 0) & (pos < npp)
+        widx = jnp.where(mine, pos, npp)   # out of local range -> dropped
+        lfeats = lfeats.at[widx].set(rows, mode="drop")
+        lgids = lgids.at[widx].set(rgids, mode="drop")
       if maintainer is not None:
         # ---- sharded (append_block x capacity) bound pass: each shard
         # sweeps the new rows against its own (already updated) block
         # columns, so the new rows' mutual/self terms are included exactly
         # once.  The per-column credit stays sharded; only the new rows'
         # own sums cross shards (one (append_block,) psum).
-        lvalid = (lgids >= 0).astype(jnp.float32)
-        add, sums_part = maintainer.append_update(
-            rows, lfeats, rvalid, lvalid, kernel=kernel, h=h,
-            backend=backend)
-        if getattr(maintainer, "sums_global", False):
-          # data-independent maintainers (e.g. the info-gain prior bound)
-          # compute each new row's COMPLETE bound identically on every
-          # shard -- a psum here would multiply it by the mesh size
-          sums = sums_part
-        else:
-          sums = jax.lax.psum(sums_part, ax)
-        lhi, llo = _df_add(lhi, llo, add)
-        lhi = lhi.at[widx].set(sums, mode="drop")
-        llo = llo.at[widx].set(jnp.zeros((ab,), jnp.float32), mode="drop")
+        with jax.named_scope("store.bound_pass"):
+          lvalid = (lgids >= 0).astype(jnp.float32)
+          add, sums_part = maintainer.append_update(
+              rows, lfeats, rvalid, lvalid, kernel=kernel, h=h,
+              backend=backend)
+          if getattr(maintainer, "sums_global", False):
+            # data-independent maintainers (e.g. the info-gain prior
+            # bound) compute each new row's COMPLETE bound identically on
+            # every shard -- a psum here would multiply it by the mesh size
+            sums = sums_part
+          else:
+            sums = jax.lax.psum(sums_part, ax)
+          lhi, llo = _df_add(lhi, llo, add)
+          lhi = lhi.at[widx].set(sums, mode="drop")
+          llo = llo.at[widx].set(jnp.zeros((ab,), jnp.float32), mode="drop")
       # device-fed diagnostics, UNCONDITIONAL extra (1,)-per-shard outputs
       # (the no-retrace contract of repro.obs); host reads them only when
       # obs is enabled
@@ -368,8 +370,9 @@ class CorpusStore:
       if maintainer is not None and sieve_state:
         # ---- standing-sieve admission rides the same pass: the psum'd
         # sums ARE the admission gains, so the sieve adds no collectives
-        sieve_state, adm, cons = sieve_body(sieve_state, rows, rgids, mine,
-                                            sums)
+        with jax.named_scope("store.sieve_scan"):
+          sieve_state, adm, cons = sieve_body(sieve_state, rows, rgids,
+                                              mine, sums)
         admitted = adm.reshape(1)
         considered = cons.reshape(1)
       return (lfeats, lgids, lhi, llo) + tuple(sieve_state) + (admitted,
@@ -569,6 +572,7 @@ class CorpusStore:
     pairwise = dispatch.resolve("pairwise", self._backend or "auto")
     n = m * t * k
 
+    @jax.named_scope("store.query_merge")
     def merge_one(sgid, sgain, sfeat, kq, excl, seed):
       gt = sgid.reshape(n)
       wt = sgain.reshape(n)
@@ -717,21 +721,23 @@ class CorpusStore:
     bq = self._qb_tile
     out_g, out_s = [], []
     for off in range(0, b, bq):
-      kc = ks[off:off + bq]
-      nb = kc.shape[0]
-      pad = bq - nb
-      if pad:
-        kc = np.pad(kc, (0, pad))  # k = 0: padding lanes pick nothing
-        ec = np.pad(exclude[off:off + bq], ((0, pad), (0, 0)),
-                    constant_values=-1)
-        sc = np.pad(seeds[off:off + bq], (0, pad))
-      else:
-        ec = exclude[off:off + bq]
-        sc = seeds[off:off + bq]
-      g, s = self._query_batch_fn(self._sieve_gid, self._sieve_gain,
-                                  self._sieve_feat, jnp.asarray(kc),
-                                  jnp.asarray(ec), jnp.asarray(sc))
-      g, s = np.asarray(g), np.asarray(s)
+      with obs.span("store.query_pack"):
+        kc = ks[off:off + bq]
+        nb = kc.shape[0]
+        pad = bq - nb
+        if pad:
+          kc = np.pad(kc, (0, pad))  # k = 0: padding lanes pick nothing
+          ec = np.pad(exclude[off:off + bq], ((0, pad), (0, 0)),
+                      constant_values=-1)
+          sc = np.pad(seeds[off:off + bq], (0, pad))
+        else:
+          ec = exclude[off:off + bq]
+          sc = seeds[off:off + bq]
+        args = jnp.asarray(kc), jnp.asarray(ec), jnp.asarray(sc)
+      with obs.span("store.query_call"):
+        g, s = self._query_batch_fn(self._sieve_gid, self._sieve_gain,
+                                    self._sieve_feat, *args)
+        g, s = np.asarray(g), np.asarray(s)
       self._feed_transfer(h2d=kc.nbytes + ec.nbytes + sc.nbytes,
                           d2h=g.nbytes + s.nbytes)
       out_g.append(g[:nb])
@@ -838,6 +844,10 @@ class CorpusStore:
     """
     if not self._sieve_k:
       return
+    with obs.span("store.reset_sieves"):
+      self._reset_sieves(sel_feats, sel_gids)
+
+  def _reset_sieves(self, sel_feats, sel_gids) -> None:
     m, t, k, d = self._m, self._sieve_T, self._sieve_k, self._d
     eps = self._sieve_eps
     delta = float(jax.device_get(jnp.max(self._ub_hi)))
@@ -903,15 +913,10 @@ class CorpusStore:
     if d2h:
       xfer.inc(d2h, path="query_d2h")
 
-  def _feed_append_metrics(self, rows_written: int, diag,
+  def _feed_append_metrics(self, rows_written: int,
                            h2d_bytes: int = 0) -> None:
-    """Feed the registry after one append chunk (docs/observability.md).
-
-    The chunk/row counters are always on (host ints).  ``diag`` is the
-    append pass's device-fed tail -- per-shard (m,) sieve admission and
-    consideration counts -- and crosses D2H only when obs is enabled, as
-    does the sieve grid-level read.
-    """
+    """Feed the always-on registry counters after one append chunk
+    (host ints only; docs/observability.md)."""
     reg = obs.REGISTRY
     reg.counter("repro_append_chunks_total",
                 "fixed-shape append chunks written").inc()
@@ -922,22 +927,31 @@ class CorpusStore:
                     h2d_bytes, path="append_h2d")
     reg.gauge("repro_store_growths", "capacity doublings so far").set(
         self._growths)
-    if not obs.enabled():
+
+  def _feed_append_diagnostics(self, diags: list) -> None:
+    """Feed the device-fed sieve counters once per ``append`` call, when
+    obs is enabled.  ``diags`` holds each chunk's (admitted, considered)
+    per-shard (m,) counts, left on the device while the chunks ran; they
+    cross D2H here in one read, with the grid level, so enabling obs never
+    stalls the chunk pipeline."""
+    if not obs.enabled() or not diags:
       return
-    admitted = int(np.asarray(diag[0]).sum())
-    considered = int(np.asarray(diag[1]).sum())
-    reg.counter("repro_sieve_admissions_total",
-                "sieve bucket admissions (device-fed)").inc(
-                    max(admitted, 0))
-    reg.counter("repro_sieve_rejections_total",
-                "sieve rows considered but not admitted (device-fed)").inc(
-                    max(considered - admitted, 0))
-    if self._sieve_k:
-      jtop = int(np.asarray(self._sieve_jtop)[0])
-      if jtop != _JTOP_COLD:
-        reg.gauge("repro_sieve_grid_level",
-                  "sieve threshold-grid top exponent jtop (device-fed)").set(
-                      jtop)
+    jtop = self._sieve_jtop if self._sieve_k else None
+    host, jtop = jax.device_get((diags, jtop))
+    reg = obs.REGISTRY
+    admissions = reg.counter("repro_sieve_admissions_total",
+                             "sieve bucket admissions (device-fed)")
+    rejections = reg.counter(
+        "repro_sieve_rejections_total",
+        "sieve rows considered but not admitted (device-fed)")
+    for adm, cons in host:
+      admitted = int(adm.sum())
+      admissions.inc(max(admitted, 0))
+      rejections.inc(max(int(cons.sum()) - admitted, 0))
+    if jtop is not None and int(jtop[0]) != _JTOP_COLD:
+      reg.gauge("repro_sieve_grid_level",
+                "sieve threshold-grid top exponent jtop (device-fed)").set(
+                    int(jtop[0]))
 
   def append(self, feats, gids=None) -> None:
     """Write documents into the resident block (chunked, fixed shapes).
@@ -956,6 +970,10 @@ class CorpusStore:
     """
     feats = np.asarray(feats, self._feat_dtype)
     assert feats.ndim == 2 and feats.shape[1] == self._d, feats.shape
+    with obs.span("store.append", rows=feats.shape[0]):
+      self._append(feats, gids)
+
+  def _append(self, feats: np.ndarray, gids) -> None:
     b = feats.shape[0]
     auto = gids is None
     if auto:
@@ -988,32 +1006,36 @@ class CorpusStore:
     self.reserve(self._n + b)
 
     ab = self._append_block
+    diags = []
     for off in range(0, b, ab):
-      chunk = feats[off:off + ab]
-      cb = chunk.shape[0]
-      pad = ab - cb
-      rows = chunk if not pad else np.concatenate(
-          [chunk, np.zeros((pad, self._d), self._feat_dtype)])
-      rgids = gids[off:off + ab] if not pad else np.concatenate(
-          [gids[off:off + ab], np.full((pad,), -1, np.int32)])
-      rvalid = np.concatenate([np.ones((cb,), np.float32),
-                               np.zeros((pad,), np.float32)])
-      state = [self._feats, self._gids, self._ub_hi, self._ub_lo]
-      if self._sieve_k:
-        state += [self._sieve_gid, self._sieve_gain, self._sieve_feat,
-                  self._sieve_cnt, self._sieve_delta, self._sieve_jtop]
-      out = self._append_fn(*state, rows, rgids, rvalid, jnp.int32(self._n))
-      self._feats, self._gids, self._ub_hi, self._ub_lo = out[:4]
-      if self._sieve_k:
-        (self._sieve_gid, self._sieve_gain, self._sieve_feat,
-         self._sieve_cnt, self._sieve_delta,
-         self._sieve_jtop) = out[4:self._n_state]
-      self._n += cb
-      # the writer's H2D traffic: only the fixed-shape chunk crosses (the
-      # resident block is donated in place), plus the n scalar
-      self._feed_append_metrics(
-          cb, out[self._n_state:],
-          h2d_bytes=rows.nbytes + rgids.nbytes + rvalid.nbytes + 4)
+      with obs.span("store.append_chunk"):
+        chunk = feats[off:off + ab]
+        cb = chunk.shape[0]
+        pad = ab - cb
+        rows = chunk if not pad else np.concatenate(
+            [chunk, np.zeros((pad, self._d), self._feat_dtype)])
+        rgids = gids[off:off + ab] if not pad else np.concatenate(
+            [gids[off:off + ab], np.full((pad,), -1, np.int32)])
+        rvalid = np.concatenate([np.ones((cb,), np.float32),
+                                 np.zeros((pad,), np.float32)])
+        state = [self._feats, self._gids, self._ub_hi, self._ub_lo]
+        if self._sieve_k:
+          state += [self._sieve_gid, self._sieve_gain, self._sieve_feat,
+                    self._sieve_cnt, self._sieve_delta, self._sieve_jtop]
+        out = self._append_fn(*state, rows, rgids, rvalid,
+                              jnp.int32(self._n))
+        self._feats, self._gids, self._ub_hi, self._ub_lo = out[:4]
+        if self._sieve_k:
+          (self._sieve_gid, self._sieve_gain, self._sieve_feat,
+           self._sieve_cnt, self._sieve_delta,
+           self._sieve_jtop) = out[4:self._n_state]
+        self._n += cb
+        diags.append(out[self._n_state:])
+        # the writer's H2D traffic: only the fixed-shape chunk crosses (the
+        # resident block is donated in place), plus the n scalar
+        self._feed_append_metrics(
+            cb, h2d_bytes=rows.nbytes + rgids.nbytes + rvalid.nbytes + 4)
+    self._feed_append_diagnostics(diags)
 
     # every chunk landed: commit the id bookkeeping
     if auto:

@@ -84,7 +84,13 @@ def compile_cache() -> str:
   fixed path ``<repo>/.jax_cache``: the directory is part of what a later
   process must find again, so it never carries a temporary name, a pid or a
   time.
+
+  The cache key includes the programs' metadata: JAX leaves it out by
+  default, and an executable cached by a build without this one's named
+  scopes would then run with the other build's op names, and a profile
+  would attribute its device time to scopes the code no longer has.
   """
+  jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
   env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
   if env:
     return env

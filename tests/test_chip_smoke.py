@@ -50,13 +50,18 @@ def test_compile_cache_goes_where_the_environment_says(monkeypatch, tmp_path):
 
   from repro.util import compile_cache
   prev = jax.config.jax_compilation_cache_dir
+  prev_meta = jax.config.jax_compilation_cache_include_metadata_in_key
   try:
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert compile_cache() == str(tmp_path)
     assert jax.config.jax_compilation_cache_dir == prev  # left to JAX
+    # a cached executable keeps the op names (scopes) of its own build
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     assert compile_cache() == os.path.join(REPO, ".jax_cache")
     assert jax.config.jax_compilation_cache_dir == os.path.join(
         REPO, ".jax_cache")
   finally:
     jax.config.update("jax_compilation_cache_dir", prev)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      prev_meta)

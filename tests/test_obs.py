@@ -142,9 +142,61 @@ def test_span_emits_jsonl_only_when_enabled(tmp_path):
   recs = [json.loads(l) for l in trace.read_text().splitlines()]
   assert [r["name"] for r in recs] == ["unit.during"]
   (r,) = recs
-  assert set(r) == {"name", "ts", "dur_s", "pid", "tid", "attrs"}
+  assert set(r) == {"name", "ts", "dur_s", "pid", "tid", "parent", "attrs"}
   assert r["attrs"] == {"k": 2, "extra": "yes"}
-  assert r["dur_s"] >= 0
+  assert r["dur_s"] >= 0 and r["parent"] is None
+
+
+def _profile(path) -> "ProfileData":
+  from jax.profiler import ProfileData
+  (pb,) = path.rglob("*.xplane.pb")
+  return ProfileData.from_file(str(pb))
+
+
+def _host_events(pd, name: str) -> list:
+  return [e for p in pd.planes if p.name.startswith("/host:")
+          for ln in p.lines for e in ln.events if e.name == name]
+
+
+def test_span_lands_in_profiler_trace_with_obs_off(tmp_path):
+  import jax
+  assert not obs.enabled()
+  jax.profiler.start_trace(str(tmp_path))
+  try:
+    with obs.span("unit.profiled"):
+      time.sleep(0.002)
+  finally:
+    jax.profiler.stop_trace()
+  (ev,) = _host_events(_profile(tmp_path), "unit.profiled")
+  assert ev.duration_ns >= 2e6
+
+
+def test_span_jsonl_ts_on_profiler_clock_and_parent(tmp_path):
+  """A record's ``ts`` is its annotation's start in the host plane (the
+  profiler's clock plus ``profile_start_time``), and ``parent`` names the
+  enclosing span of the same thread."""
+  import jax
+  trace = tmp_path / "trace.jsonl"
+  obs.enable(trace_out=str(trace))
+  jax.profiler.start_trace(str(tmp_path / "prof"))
+  try:
+    with obs.span("unit.outer"):
+      time.sleep(0.002)
+      with obs.span("unit.inner"):
+        time.sleep(0.002)
+  finally:
+    jax.profiler.stop_trace()
+    obs.disable()
+  recs = {r["name"]: r for r in map(json.loads,
+                                    trace.read_text().splitlines())}
+  assert recs["unit.outer"]["parent"] is None
+  assert recs["unit.inner"]["parent"] == "unit.outer"
+  pd = _profile(tmp_path / "prof")
+  (start,) = [int(v) for p in pd.planes for k, v in p.stats
+              if k == "profile_start_time"]
+  for name in ("unit.outer", "unit.inner"):
+    (ev,) = _host_events(pd, name)
+    assert abs((start + ev.start_ns) * 1e-9 - recs[name]["ts"]) < 1e-3
 
 
 # ----------------------------------------------------------------- sidecar
@@ -260,7 +312,8 @@ print("CONTRACT_OK")
   assert names.count("service.query") == 3
   assert names.count("service.query_batch") == 4
   for r in recs:
-    assert set(r) == {"name", "ts", "dur_s", "pid", "tid", "attrs"}
+    assert set(r) == {"name", "ts", "dur_s", "pid", "tid", "parent",
+                      "attrs"}
   epochs = [r for r in recs if r["name"] == "service.epoch"]
   assert [e["attrs"]["epoch"] for e in epochs] == [0, 1, 2]
 
@@ -316,22 +369,35 @@ print("SIDECAR_LIVENESS_OK")
 # -------------------------------------------------- batcher latency SLO
 
 
-def test_batcher_latency_slo_under_slow_worker():
-  """Submit-to-result latency stays bounded by max_delay plus one batch
-  service time even when the device worker is slow -- the deadline drain
-  fires on the clock, never waits for a full tile."""
+SLOW = 0.05
+
+
+@pytest.fixture
+def slow_worker():
+  """A warmed service whose batched query first sleeps ``SLOW`` (the slow
+  device worker); returns it, the wall of one real batched call, and the
+  start time of each slow call."""
   svc = _service()
   svc.query()                              # warm the single-query path
   t0 = time.perf_counter()
   real = svc.query_batch
   real([QueryRequest()])                   # warm the batch path
   t_batch = time.perf_counter() - t0
+  started = []
 
-  SLOW = 0.05
   def slow_query_batch(reqs, tier="sieve"):
+    started.append(time.perf_counter())
     time.sleep(SLOW)                       # the slow worker
     return real(reqs, tier=tier)
   svc.query_batch = slow_query_batch
+  return svc, t_batch, started
+
+
+def test_batcher_latency_slo_under_slow_worker(slow_worker):
+  """Submit-to-result latency stays bounded by max_delay plus one batch
+  service time even when the device worker is slow -- the deadline drain
+  fires on the clock, never waits for a full tile."""
+  svc, t_batch, _ = slow_worker
 
   MAX_DELAY = 0.02
   reg = obs.REGISTRY
@@ -371,3 +437,130 @@ def test_batcher_stats_reconcile_under_concurrency():
   assert st.batches >= 3                   # 10 requests, tile of 4
   assert st.mean_occupancy * st.batches == pytest.approx(st.served)
   assert st.max_occupancy <= 4
+
+
+def test_batcher_queue_wait_counts_the_drain_in_flight(slow_worker):
+  """A request submitted while a slow drain runs waits at least the rest of
+  that drain, and ``repro_batcher_queue_wait_seconds`` records it."""
+  import threading
+  svc, _, started = slow_worker
+  hist = obs.REGISTRY.histogram("repro_batcher_queue_wait_seconds")
+  slow = svc.query_batch
+  first = {}
+  in_drain = threading.Event()
+
+  def watched(reqs, tier="sieve"):
+    if not in_drain.is_set():
+      first["hist"] = hist.get()           # the first request's wait is in
+      in_drain.set()
+    return slow(reqs, tier=tier)
+  svc.query_batch = watched
+  with QueryBatcher(svc, max_batch=4, max_delay_s=0.02) as qb:
+    fa = qb.submit()
+    assert in_drain.wait(30)
+    fb = qb.submit()
+    t_b = time.perf_counter()              # after b's enqueue stamp
+    fa.result(timeout=30)
+    fb.result(timeout=30)
+  remaining = started[0] + SLOW - t_b
+  assert remaining > SLOW / 2, remaining   # b arrived mid-drain
+  got = hist.get()
+  assert got["count"] - first["hist"]["count"] == 1
+  assert got["sum"] - first["hist"]["sum"] >= remaining
+
+
+# ------------------------------------------ named scopes, append diagnostics
+
+
+def _compiled_entries(svc):
+  """Compiled HLO text of the epoch, append and batched-merge programs."""
+  import jax
+  import jax.numpy as jnp
+  st = svc.store
+  svc.query_batch([QueryRequest(k=3, seed=1)])
+  ages = jnp.asarray(svc.board.ages(), jnp.float32)
+  epoch = svc._epoch_fn.lower(st.feats, st.gids, st.ubound_device, ages,
+                              jnp.float32(np.inf), jax.random.PRNGKey(0))
+  ab = 128
+  state = [st._feats, st._gids, st._ub_hi, st._ub_lo, st._sieve_gid,
+           st._sieve_gain, st._sieve_feat, st._sieve_cnt, st._sieve_delta,
+           st._sieve_jtop]
+  append = st._append_fn.lower(*state, np.zeros((ab, D), np.float32),
+                               np.zeros((ab,), np.int32),
+                               np.zeros((ab,), np.float32), jnp.int32(0))
+  bq, mc = st.query_batch_tile, st.query_mask_cap
+  merge = st._query_batch_fn.lower(
+      st._sieve_gid, st._sieve_gain, st._sieve_feat,
+      jnp.zeros((bq,), jnp.int32), jnp.zeros((bq, mc), jnp.int32),
+      jnp.zeros((bq,), jnp.int32))
+  return {k: v.compile().as_text()
+          for k, v in (("epoch", epoch), ("append", append),
+                       ("merge", merge))}
+
+
+def _n_instructions(hlo: str) -> int:
+  import re
+  return sum(1 for line in hlo.splitlines()
+             if re.match(r"\s*(ROOT\s+)?%[\w.\-]+ = ", line))
+
+
+def test_named_scopes_reach_the_hlo_and_change_no_instruction(monkeypatch):
+  """The GreeDi rounds and the store passes carry their scope in the
+  compiled HLO's op_name metadata, and the same programs compiled with
+  every scope taken out have exactly as many instructions."""
+  import contextlib
+  import re
+  import jax
+  scoped = _compiled_entries(_service(append_block=128))
+  for name, scope in (("epoch", "greedi.partition"),
+                      ("epoch", "greedi.round1"), ("epoch", "greedi.merge"),
+                      ("epoch", "greedi.round2"),
+                      ("append", "store.bound_pass"),
+                      ("append", "store.sieve_scan"),
+                      ("append", "store.write"),
+                      ("merge", "store.query_merge")):
+    assert re.search(rf"[/(]{scope}[/)]", scoped[name]), (name, scope)
+  monkeypatch.setattr(jax, "named_scope",
+                      contextlib.contextmanager(lambda name: (yield)))
+  bare = _compiled_entries(_service(append_block=128))
+  for name in scoped:
+    assert "greedi." not in bare[name].replace("greedi.py", "")
+    assert _n_instructions(scoped[name]) == _n_instructions(bare[name])
+
+
+def test_append_reads_sieve_diagnostics_once_per_call(monkeypatch):
+  """With obs on, one append of several chunks crosses D2H once for its
+  device-fed diagnostics, and its counters end equal to the sums of the
+  same chunks appended one call each."""
+  import jax
+  rng = np.random.default_rng(3)
+  feats = rng.standard_normal((512, D)).astype(np.float32)
+  feats /= np.linalg.norm(feats, axis=1, keepdims=True)
+  reg = obs.REGISTRY
+  names = ("repro_sieve_admissions_total", "repro_sieve_rejections_total")
+
+  def run(calls):
+    svc = SelectionService(make_mesh((1,), ("data",)), d=D, kappa=KAPPA,
+                           k_final=K, capacity=1024, append_block=128,
+                           seed=0)
+    svc.append(feats[:128])                # compile outside the count
+    before = [reg.counter(n).get() for n in names]
+    reads = []
+    real = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: reads.append(1) or real(x))
+    for rows in np.split(feats[128:], calls):
+      svc.append(rows)
+    monkeypatch.setattr(jax, "device_get", real)
+    return ([reg.counter(n).get() - b for n, b in zip(names, before)],
+            reg.gauge("repro_sieve_grid_level").get(), len(reads))
+
+  obs.enable()
+  try:
+    once, level_once, reads_once = run(1)    # 3 chunks in one call
+    each, level_each, reads_each = run(3)    # one chunk per call
+  finally:
+    obs.disable()
+  assert reads_once == 1 and reads_each == 3
+  assert once == each and once[0] > 0
+  assert level_once == level_each
