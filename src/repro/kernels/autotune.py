@@ -125,11 +125,11 @@ def lazy_tile(n: int, d: int, backend: str | None = None) -> int:
 
 
 # backend -> query-batch tile of the multi-tenant batched query path
-# (service/store.py).  The tile is the compiled batch width B of the vmapped
-# sieve merge / batched select oracles: ragged request batches pad up to it
+# (service/store.py).  The tile is the compiled batch width B of the sieve
+# merge / batched select oracles: ragged request batches pad up to it
 # (so they never retrace) and bigger batches chunk through it.  TPU lanes
-# want a wider tile to fill the VPU; on CPU the vmapped merge is a batched
-# matmul whose win saturates around 64 concurrent queries.
+# want a wider tile to fill the VPU; on CPU the merge's batched matmul
+# win saturates around 64 concurrent queries.
 _QUERY_TILE: dict[str, int] = {
     "tpu": 128,
     "cpu": 64,

@@ -29,7 +29,7 @@ Transfer accounting (what actually crosses H2D; docs/service.md):
   * ``query_batch`` -- one batched merge call per query tile: the per-query
     (k, exclusion list, seed) triples cross H2D (O(B * query_mask_cap)
     ints) and the (B, k) winners + scores cross D2H; the sieve state is
-    shared across all lanes of the vmapped merge.  The exact tier
+    shared across all lanes of the batched merge.  The exact tier
     additionally reads the resident block (still zero H2D for it).
 
 Select-on-append (the sieve): when the maintainer supports it (sum-form
@@ -548,11 +548,18 @@ class CorpusStore:
     no (N, N) matrix is ever materialized.  A gid admitted into several
     buckets dedupes itself twice over: the second copy is fully redundant
     with the first (red == 1 -> score == 0) AND explicitly masked by gid
-    against the picks so far -- the explicit mask is what makes dedup
+    once the first is picked -- the explicit mask is what makes dedup
     rounding-independent (see the step body).  Greedy picks are nested, so
-    a caller
-    wanting k' < k representatives takes the first k' outputs.  Only the
-    (k,) winners + scores leave the device.
+    a caller wanting k' < k representatives takes the first k' outputs.
+    Only the (k,) winners + scores leave the device.
+
+    The body (``merge_tile``) is written over an explicit lane axis of B
+    queries sharing the pool: each greedy step gathers the B lanes' picks
+    as a (B, d) block and makes ONE (N x d) . (d x B) similarity call for
+    all of them, so a step costs one pass over the pool whatever B is, and
+    the step state is O(N * B).  The single-query merge is the same body
+    at B = 1; the batched merge (``_compile_query_batch``) runs it at the
+    query tile.
 
     Per-query parameters (all runtime arguments, so they never retrace):
 
@@ -573,75 +580,82 @@ class CorpusStore:
     n = m * t * k
 
     @jax.named_scope("store.query_merge")
-    def merge_one(sgid, sgain, sfeat, kq, excl, seed):
+    def merge_tile(sgid, sgain, sfeat, kq, excl, seed):
+      """(B,) kq, (B, query_mask_cap) excl, (B,) seed -> (B, k) gids and
+      scores; the pool (sgid, sgain, sfeat) is shared by every lane."""
+      b = kq.shape[0]
       gt = sgid.reshape(n)
       wt = sgain.reshape(n)
       ft = sfeat.reshape(n, self._d).astype(jnp.float32)
       if kernel == "linear":
         nsq = jnp.maximum(jnp.sum(ft * ft, -1), 1e-12)
-      ok = (gt >= 0) & ~jnp.any(gt[:, None] == excl[None, :], axis=1)
-      u = jax.random.uniform(jax.random.PRNGKey(seed), (n,), jnp.float32)
-      mult = jnp.where(seed != 0, 1.0 + _QUERY_JITTER * u, 1.0)
+      ok = (gt >= 0) & ~jnp.any(gt[None, :, None] == excl[:, None, :], axis=2)
+      u = jax.vmap(lambda s: jax.random.uniform(
+          jax.random.PRNGKey(s), (n,), jnp.float32))(seed)
+      mult = jnp.where(seed[:, None] != 0, 1.0 + _QUERY_JITTER * u, 1.0)
 
       def step(i, c):
         picked, redmax, out_g, out_s = c
         score = wt * jnp.maximum(1.0 - redmax, 0.0) * mult
-        # gid-level dedup of already-picked documents: a doc admitted into
-        # several buckets must not be returned twice.  The redundancy
-        # discount alone is not enough -- red == 1 can round to 1 +/- ulp,
-        # and under seed jitter a leftover ~ulp score re-picks the copy
-        # (and does so differently in the single vs vmapped executable).
-        # -1 slots of out_g never match: hole candidates are already
-        # dropped by ``ok``.
-        dup = jnp.any(gt[:, None] == out_g[None, :], axis=1)
-        score = jnp.where(ok & ~picked & ~dup, score, _NEG)
-        j = jnp.argmax(score).astype(jnp.int32)
-        s = score[j]
+        # ``picked`` masks every pool slot whose gid a lane has already
+        # taken: a doc admitted into several buckets must not be returned
+        # twice.  The redundancy discount alone is not enough -- red == 1
+        # can round to 1 +/- ulp, and under seed jitter a leftover ~ulp
+        # score re-picks the copy (and does so differently in the single
+        # vs batched executable).
+        score = jnp.where(ok & ~picked, score, _NEG)
+        j = jnp.argmax(score, axis=1).astype(jnp.int32)
+        s = jnp.max(score, axis=1)
         take = (s > 0.0) & (i < kq)
-        out_g = out_g.at[i].set(jnp.where(take, gt[j], -1))
-        out_s = out_s.at[i].set(jnp.where(take, s, 0.0))
-        picked = picked | (take & (jnp.arange(n) == j))
-        simj = pairwise(ft, ft[j][None], kernel=kernel, h=h)[:, 0]
+        gj = gt[j]
+        out_g = out_g.at[:, i].set(jnp.where(take, gj, -1))
+        out_s = out_s.at[:, i].set(jnp.where(take, s, 0.0))
+        picked = picked | (take[:, None] & (gt[None, :] == gj[:, None]))
+        # one similarity call for the whole tile: pool x the B picked rows
+        simj = pairwise(ft, ft[j], kernel=kernel, h=h).T
         if kernel == "linear":
-          redj = jnp.maximum(simj, 0.0) / jnp.sqrt(nsq * nsq[j])
+          redj = jnp.maximum(simj, 0.0) / jnp.sqrt(nsq * nsq[j][:, None])
         else:
           redj = simj
-        redmax = jnp.where(take, jnp.maximum(redmax, redj), redmax)
+        redmax = jnp.where(take[:, None], jnp.maximum(redmax, redj), redmax)
         return picked, redmax, out_g, out_s
 
-      init = (jnp.zeros((n,), bool), jnp.zeros((n,), jnp.float32),
-              jnp.full((k,), -1, jnp.int32), jnp.zeros((k,), jnp.float32))
+      init = (jnp.zeros((b, n), bool), jnp.zeros((b, n), jnp.float32),
+              jnp.full((b, k), -1, jnp.int32), jnp.zeros((b, k), jnp.float32))
       _, _, out_g, out_s = jax.lax.fori_loop(0, k, step, init)
       return out_g, out_s
+
+    def merge_one(sgid, sgain, sfeat, kq, excl, seed):
+      g, s = merge_tile(sgid, sgain, sfeat, kq[None], excl[None], seed[None])
+      return g[0], s[0]
 
     def merge(sgid, sgain, sfeat, kq, excl, seed):
       self._query_trace_count += 1  # python side effect: counts traces
       return self._replicated(merge_one)(sgid, sgain, sfeat, kq, excl, seed)
 
     # raw bodies kept for the analyzer (repro.analysis.entries) and for the
-    # batched compile (the batched merge is the SAME body vmapped over the
-    # per-query arguments, sieve state shared)
-    self._merge_one = merge_one
+    # batched compile (the same body at the query tile's lane count)
+    self._merge_tile = merge_tile
     self._query_raw = merge
     self._query_fn = jax.jit(merge)
 
   def _compile_query_batch(self) -> None:
-    """One jit for the BATCHED sieve merge: ``merge_one`` vmapped over the
-    per-query (kq, excl, seed) triple with the sieve state shared across
-    lanes, so one scan of the standing summaries answers a whole query
-    batch.  The compiled batch width is the fixed ``query_batch_tile``
-    (ragged batches pad, bigger batches chunk), and shapes stay
-    capacity-independent -- the batched merge traces exactly once for the
-    store lifetime (``query_batch_trace_count``)."""
+    """One jit for the BATCHED sieve merge: ``merge_tile`` over the
+    per-query (kq, excl, seed) triples of a whole query tile, the sieve
+    state shared across lanes.  Each greedy step is one (N x d) . (d x B)
+    similarity call for all B lanes (O(N * B) memory), so one pass over the
+    standing summaries per step answers the whole batch.  The compiled
+    batch width is the fixed ``query_batch_tile`` (ragged batches pad,
+    bigger batches chunk), and shapes stay capacity-independent -- the
+    batched merge traces exactly once for the store lifetime
+    (``query_batch_trace_count``)."""
     if self._query_fn is None:
       self._compile_query()
-    merge_one = self._merge_one
+    merge_tile = self._merge_tile
 
     def merge_batch(sgid, sgain, sfeat, kq, excl, seeds):
       self._query_batch_trace_count += 1  # python side effect: trace count
-      return self._replicated(jax.vmap(
-          merge_one, in_axes=(None, None, None, 0, 0, 0)))(
-              sgid, sgain, sfeat, kq, excl, seeds)
+      return self._replicated(merge_tile)(sgid, sgain, sfeat, kq, excl, seeds)
 
     # raw body kept for the analyzer (repro.analysis.entries)
     self._query_batch_raw = merge_batch
@@ -704,7 +718,7 @@ class CorpusStore:
     traces exactly once for the store lifetime.  Returns host
     (B, sieve_k) gids / scores; each lane selects exactly what the
     single-query merge selects at the same (k, excl, seed) -- scores agree
-    to ~ulp only, because the vmapped and single merges are different XLA
+    to ~ulp only, because the batched and single merges are different XLA
     executables and may round the d-dim reductions differently (selection
     parity survives that because near-equal candidates are either the same
     gid, deduped exactly, or decorrelated by the seed jitter).

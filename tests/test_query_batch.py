@@ -137,6 +137,134 @@ def test_exclusions_actually_hide_gids():
 
 
 # ---------------------------------------------------------------------------
+# the merge over the whole tile: structure and a host reference
+# ---------------------------------------------------------------------------
+
+
+def _loop_similarity_calls(jaxpr):
+  """(primitive, eqn) of every similarity call inside a loop body of
+  ``jaxpr``: pallas_calls, and dot_generals outside any pallas kernel."""
+  found = []
+
+  def walk(j, in_loop):
+    for e in j.eqns:
+      name = e.primitive.name
+      if in_loop and name in ("pallas_call", "dot_general"):
+        found.append((name, e))
+      if name == "pallas_call":
+        continue                     # the kernel's own body is not a call
+      for p in e.params.values():
+        for sub in (p if isinstance(p, (list, tuple)) else [p]):
+          inner = getattr(sub, "jaxpr", sub)
+          if hasattr(inner, "eqns"):
+            walk(inner, in_loop or name in ("while", "scan"))
+
+  walk(jaxpr, False)
+  return found
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
+@pytest.mark.parametrize("kernel", ["linear", "rbf"])
+def test_batched_merge_makes_one_similarity_call_per_step(kernel, backend):
+  """Each greedy step of the batched merge is ONE similarity call covering
+  every lane of the tile: the pool against the (B, d) picked rows -> (n, B),
+  never a per-lane call (no lane axis in a pallas grid, no lane-batched
+  dot_general)."""
+  import jax
+  import jax.numpy as jnp
+  svc = _service(kernel=kernel, backend=backend, query_batch_tile=4)
+  st = svc.store
+  st._compile_query_batch()
+  t, k, m = st.sieve_thresholds, st.sieve_k, st._m
+  n, b, mc = m * t * k, st.query_batch_tile, st.query_mask_cap
+  sds = jax.ShapeDtypeStruct
+  jaxpr = jax.make_jaxpr(st._query_batch_raw)(
+      sds((m * t, k), jnp.int32), sds((m * t, k), jnp.float32),
+      sds((m * t, k, D), jnp.float32), sds((b,), jnp.int32),
+      sds((b, mc), jnp.int32), sds((b,), jnp.int32))
+  calls = _loop_similarity_calls(jaxpr.jaxpr)
+  assert len(calls) == 1, [(nm, e.outvars[0].aval.shape) for nm, e in calls]
+  name, eqn = calls[0]
+  assert name == ("pallas_call" if backend == "pallas" else "dot_general")
+  if name == "pallas_call":
+    assert len(eqn.params["grid_mapping"].grid) == 2
+    pool, picks = (v.aval.shape for v in eqn.invars)
+    (out,) = (v.aval.shape for v in eqn.outvars)
+    assert pool[0] >= n and picks[0] >= b and out == (pool[0], picks[0])
+  else:
+    assert eqn.outvars[0].aval.shape == (n, b)
+
+
+def _np_merge(gid, gain, feat, kq, excl, seed, kernel, h=0.75):
+  """Host greedy MMR over the pooled sieve members, float64: the merge's
+  semantics written out plainly (tie-break jitter from the same seeded
+  uniform draw)."""
+  import jax
+  n, k = gid.size, gid.shape[-1]
+  g = gid.reshape(n)
+  w = gain.reshape(n).astype(np.float64)
+  f = feat.reshape(n, -1).astype(np.float64)
+  if kernel == "linear":
+    nsq = np.maximum((f * f).sum(-1), 1e-12)
+    red = np.maximum(f @ f.T, 0.0) / np.sqrt(nsq[:, None] * nsq[None, :])
+  else:
+    sq = (f * f).sum(-1)
+    d2 = np.maximum(sq[:, None] - 2.0 * f @ f.T + sq[None, :], 0.0)
+    red = np.exp(-d2 / (h * h))
+  mult = np.ones(n)
+  if seed:
+    u = np.asarray(jax.random.uniform(jax.random.PRNGKey(seed), (n,)))
+    mult = 1.0 + 1e-4 * u.astype(np.float64)
+  ok = (g >= 0) & ~np.isin(g, excl[excl >= 0])
+  redmax = np.zeros(n)
+  out_g, out_s = np.full(k, -1), np.zeros(k)
+  for i in range(min(kq, k)):
+    score = np.where(ok, w * np.maximum(1.0 - redmax, 0.0) * mult, -np.inf)
+    j = int(np.argmax(score))
+    if score[j] <= 0.0:
+      break
+    out_g[i], out_s[i] = g[j], score[j]
+    ok &= g != g[j]
+    redmax = np.maximum(redmax, red[j])
+  return out_g, out_s
+
+
+@pytest.mark.parametrize("kernel", ["linear", "rbf"])
+def test_batched_merge_matches_host_greedy_mmr(kernel):
+  """Every lane of a ragged tile -- mixed k (inert k = 0 lanes included),
+  exclusions, zero and nonzero seeds -- selects what a plain host greedy
+  MMR over the pooled sieve members selects, and so do the single-query
+  merge and a batch of one."""
+  svc = _service(n_docs=384, kernel=kernel, query_batch_tile=8)
+  st = svc.store
+  gid, gain, feat = st.sieve_state_host()[:3]
+  base = svc.query()
+  mc = st.query_mask_cap
+  excl = np.full((5, mc), -1, np.int32)
+  excl[1, :3] = base.sel_gids[:3]
+  excl[3, :2] = base.sel_gids[2:4]
+  excl[4, :1] = base.sel_gids[:1]
+  ks = np.array([K, 3, 0, K, 5], np.int32)
+  seeds = np.array([0, 7, 11, 2**31 - 1, 0], np.int32)
+  got_g, got_s = st.query_sieves_batch(ks, excl, seeds)   # 5 of 8 lanes
+  assert got_g.shape == (5, K)
+  for b in range(5):
+    want_g, want_s = _np_merge(gid, gain, feat, ks[b], excl[b], seeds[b],
+                               kernel)
+    np.testing.assert_array_equal(got_g[b], want_g, err_msg=str(b))
+    np.testing.assert_allclose(got_s[b], want_s, rtol=1e-5, atol=1e-7)
+  assert (got_g[2] == -1).all() and (got_s[2] == 0).all()  # k = 0: inert
+  assert (got_g[0] >= 0).sum() > 3                          # a real answer
+  # B = 1: the single-query merge and a one-lane batch
+  want_g, want_s = _np_merge(gid, gain, feat, 6, excl[1], 5, kernel)
+  one_g, one_s = st.query_sieves(6, excl[1], seed=5)
+  bat_g, bat_s = st.query_sieves_batch([6], excl[1:2], [5])
+  for g, s in ((one_g, one_s), (bat_g[0], bat_s[0])):
+    np.testing.assert_array_equal(g, want_g)
+    np.testing.assert_allclose(s, want_s, rtol=1e-5, atol=1e-7)
+
+
+# ---------------------------------------------------------------------------
 # satellite 1: empty sieve slots must not pollute value_estimate
 # ---------------------------------------------------------------------------
 
