@@ -3,6 +3,9 @@ comparison with the plain reference once the window has closed.
 
 A driver is built from a cell's configuration and traffic mix (plain dicts)
 and never from its name, so a new cell of an existing kind is data only.
+The traffic kind, the corpus kind and the objective are found by
+``registry.lookup``: the tables here first, then a file of the cell's tree
+(``kinds/``, ``corpora/``, ``objectives/``; see ``bench/__init__.py``).
 """
 from __future__ import annotations
 
@@ -13,10 +16,37 @@ import time
 
 import numpy as np
 
-from bench import corpus, reference, seeds, traffic
+from bench import corpus, reference, registry, seeds, traffic
 
 # how long the window's answers may take to come in after it closes
 LATE_S = 60.0
+
+
+def _facility_epoch(x, rng, m: int, cfg: dict, sel_gids, sel_feats,
+                    value: float) -> dict:
+  return reference.epoch_numbers(x, rng, m, int(cfg["kappa"]), sel_gids,
+                                 sel_feats, value)
+
+
+# objective -> the numbers of one checked epoch against its plain reference
+EPOCH_REFERENCES = {"facility": _facility_epoch}
+# corpus kind -> draw(cfg, n, seed): (n, d) rows on the default device and
+# (n,) labels, label 0 the most common
+CORPORA = {"clusters": corpus.draw}
+
+
+def epoch_reference(cfg: dict, root=registry.ROOT):
+  return registry.lookup(EPOCH_REFERENCES, root, "objectives",
+                         cfg["objective"], "epoch_numbers")
+
+
+def corpus_draw(cfg: dict, root=registry.ROOT):
+  return registry.lookup(CORPORA, root, "corpora",
+                         cfg["corpus"].get("kind", "clusters"), "draw")
+
+
+def driver_for(kind: str, root=registry.ROOT):
+  return registry.lookup(KINDS, root, "kinds", kind, "Driver")
 
 
 def _service(cfg: dict, mesh, capacity: int, **kw):
@@ -25,7 +55,9 @@ def _service(cfg: dict, mesh, capacity: int, **kw):
   return SelectionService(
       mesh, d=int(cfg["d"]), kappa=int(cfg["kappa"]),
       k_final=int(cfg["k_final"]), capacity=capacity,
-      kernel=cfg["kernel"], mode=cfg["mode"],
+      kernel=cfg["kernel"],
+      kernel_kwargs=tuple(sorted(cfg.get("kernel_kwargs", {}).items())),
+      mode=cfg["mode"],
       warm_start=bool(cfg["warm_start"]), seed=0,
       append_block=int(cfg["append_block"]),
       feat_dtype=jnp.dtype(cfg["feat_dtype"]),
@@ -49,14 +81,25 @@ def _sieves(cfg: dict, x, stored: int, state, rows_per_shard: int):
                           float(cfg["sieve_eps"]), rows_per_shard)
 
 
+def _facility_only(cfg: dict, kind: str) -> None:
+  """The bound, sieve and merge references are facility location's."""
+  if cfg["objective"] != "facility":
+    raise ValueError(
+        f"{kind} traffic is checked against facility-location references "
+        f"(bound table, sieves, merge) only; objective "
+        f"{cfg['objective']!r} has none")
+
+
 class Driver:
-  """Shared shape of a driver; subclasses fill in the kind."""
+  """Shared shape of a driver; subclasses fill in the kind.  ``root`` is
+  the tree the cell was read from: its files are found there."""
 
   def __init__(self, cfg: dict, mix: dict, seed: int, chips: int, devices,
-               seconds: float | None = None):
+               seconds: float | None = None, root=registry.ROOT):
     self.cfg, self.mix, self.seed, self.chips = cfg, mix, seed, chips
     self.devices = devices
     self.seconds = seconds
+    self.root = root
     self.attempted = 0
     self.failed = 0
     self.counters: dict = {}
@@ -69,8 +112,9 @@ class Epochs(Driver):
   def setup(self) -> None:
     import jax
     cfg = self.cfg
+    self.epoch_numbers = epoch_reference(cfg, self.root)
     self.n = int(cfg["rows_per_chip"]) * self.chips
-    xd, _ = corpus.draw(cfg, self.n, self.seed)
+    xd, _ = corpus_draw(cfg, self.root)(cfg, self.n, self.seed)
     self.x = np.asarray(xd)
     del xd
     self.svc = _service(cfg, _mesh(self.devices, self.chips), self.n)
@@ -125,8 +169,7 @@ class Epochs(Driver):
     out: dict = {}
     for i in sorted(pick):
       rng, g, f, v = self.results[i]
-      nums = reference.epoch_numbers(self.x, rng, self.chips,
-                                     int(self.cfg["kappa"]), g, f, v)
+      nums = self.epoch_numbers(self.x, rng, self.chips, self.cfg, g, f, v)
       for k, val in nums.items():
         out[k] = max(out.get(k, 0.0), val)
     self.counters["checked_epochs"] = len(pick)
@@ -142,6 +185,7 @@ class BulkAppend(Driver):
   def setup(self) -> None:
     import jax
     cfg, mix = self.cfg, self.mix
+    _facility_only(cfg, "bulk_append")
     self.cap = int(cfg["capacity_per_chip"]) * self.chips
     self.call = int(mix["call_rows"])
     n = self.cap
@@ -149,7 +193,7 @@ class BulkAppend(Driver):
       calls = 1 + math.ceil(float(mix["draw_rows_per_s"]) * self.seconds
                             / self.call)
       n = min(n, calls * self.call)
-    xd, _ = corpus.draw(cfg, n, self.seed)
+    xd, _ = corpus_draw(cfg, self.root)(cfg, n, self.seed)
     self.x = np.asarray(xd)
     del xd
     self.svc = _service(cfg, _mesh(self.devices, self.chips), self.cap)
@@ -208,9 +252,10 @@ class TenantQueries(Driver):
     from repro.service import QueryRequest
     from repro.service.batching import QueryBatcher
     cfg, mix = self.cfg, self.mix
+    _facility_only(cfg, "tenant_queries")
     self.cap = int(cfg["capacity_per_chip"]) * self.chips
     self.stored = int(mix["setup_rows"])
-    xd, assign = corpus.draw(cfg, self.stored, self.seed)
+    xd, assign = corpus_draw(cfg, self.root)(cfg, self.stored, self.seed)
     self.x = np.asarray(xd)
     assign = np.asarray(assign)
     del xd

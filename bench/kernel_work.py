@@ -9,8 +9,14 @@ once against the bfloat16 peak, whatever the precision the kernel asks for,
 and bytes once per operand: a kernel whose grid reads an operand more than
 once, or whose float32 contraction takes several passes of the MXU, stays
 below 100% by that much (PERF.md gives each kernel's ceiling).
+
+A kernel outside ``WORK`` brings its arithmetic as a file
+``bench/work/<stem>.py`` that defines ``work(operands, results) ->
+(operations, bytes)``.
 """
 from __future__ import annotations
+
+from bench import registry
 
 DTYPE_BYTES = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2, "s8": 1,
                "u8": 1, "pred": 1, "f64": 8, "s64": 8, "u64": 8, "s16": 2,
@@ -55,7 +61,11 @@ WORK = {
 }
 
 
-def work(kernel: str, operands, results):
-  """(operations, bytes) of one call of ``kernel``, or None if unknown."""
-  fn = WORK.get(kernel)
-  return None if fn is None else fn(operands, results)
+def work(kernel: str, operands, results, root=registry.ROOT):
+  """(operations, bytes) of one call of ``kernel``, from ``WORK`` or from
+  ``bench/work/<kernel>.py`` under ``root``; None if neither knows it."""
+  try:
+    fn = registry.lookup(WORK, root, "work", kernel, "work")
+  except KeyError:
+    return None
+  return fn(operands, results)

@@ -28,8 +28,9 @@ def sweep(cell: registry.Cell, seed: int, seconds: float, rates, devices):
   import jax
   compile_cache()
   jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-  drv = drivers.KINDS[cell.traffic["kind"]](
-      cell.config, dict(cell.traffic), seed, cell.chips, devices)
+  drv = drivers.driver_for(cell.traffic["kind"], cell.root)(
+      cell.config, dict(cell.traffic), seed, cell.chips, devices,
+      root=cell.root)
   drv.setup()
   out = []
   for rate in rates:

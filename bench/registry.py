@@ -1,13 +1,17 @@
 """Find a cell's configuration, traffic mix and per-layer metric readers by
-the names in BENCHMARK.json."""
+the names in BENCHMARK.json, and the code a name stands for: a built-in
+table first, then a file ``bench/<directory>/<name>.py`` of the cell's
+tree (``lookup``)."""
 from __future__ import annotations
 
 import importlib.util
 import json
 import pathlib
+import re
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
+NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
 
 
 def load_benchmark(root: pathlib.Path = ROOT) -> dict:
@@ -20,6 +24,28 @@ def _by_name(entries: list[dict], name: str, what: str) -> dict:
     if e["name"] == name:
       return e
   raise KeyError(f"no {what} named {name!r} in BENCHMARK.json")
+
+
+def load(root: pathlib.Path, directory: str, name: str):
+  """The module ``bench/<directory>/<name>.py`` under ``root``; a KeyError
+  naming that file where there is none."""
+  path = root / "bench" / directory / f"{name}.py"
+  if not NAME.fullmatch(name) or not path.is_file():
+    raise KeyError(f"no {directory} entry {name!r}: {path} not found")
+  spec = importlib.util.spec_from_file_location(
+      f"bench_{directory}_{re.sub(r'[^A-Za-z0-9_]', '_', name)}", path)
+  mod = importlib.util.module_from_spec(spec)
+  spec.loader.exec_module(mod)
+  return mod
+
+
+def lookup(table: dict, root: pathlib.Path, directory: str, name: str,
+           attr: str):
+  """``table[name]``, else ``attr`` of ``bench/<directory>/<name>.py``
+  under ``root`` (a KeyError naming that file where there is none)."""
+  if name in table:
+    return table[name]
+  return getattr(load(root, directory, name), attr)
 
 
 class Cell:
@@ -51,9 +77,4 @@ class Cell:
 
   def reader(self, metric: str):
     """The ``read(ctx)`` function of ``metrics/<metric>.py``."""
-    path = self.root / "bench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load(self.root, "metrics", metric).read

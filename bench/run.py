@@ -41,13 +41,14 @@ class NoChip(RuntimeError):
 class Context:
   """What a per-layer metric reader may read."""
 
-  def __init__(self, red, peaks, counters):
+  def __init__(self, red, peaks, counters, root=registry.ROOT):
     self.trace = red
     self.peaks = peaks
     self.counters = counters
+    self.root = root
 
   def roofline(self, kernel: str):
-    return tracing.roofline_pct(self.trace, kernel, self.peaks)
+    return tracing.roofline_pct(self.trace, kernel, self.peaks, self.root)
 
 
 def devices_for(chips: int):
@@ -68,15 +69,15 @@ def load_limits(root: pathlib.Path, workload: str) -> dict:
 
 
 def run(cell: registry.Cell, seed: int, seconds: float, trace: bool,
-        devices, t_start: float = T_START, driver_cls=None) -> dict:
+        devices, t_start: float = T_START) -> dict:
   """One run of ``cell``; returns the result object."""
   import jax
   from repro.util import compile_cache
   compile_cache()
   jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-  kind = cell.traffic["kind"]
-  drv = (driver_cls or drivers.KINDS[kind])(
-      cell.config, cell.traffic, seed, cell.chips, devices, seconds)
+  drv = drivers.driver_for(cell.traffic["kind"], cell.root)(
+      cell.config, cell.traffic, seed, cell.chips, devices, seconds,
+      root=cell.root)
   drv.setup()
   # everything set-up made lives as long as the run: a full collection
   # inside the window scans only what the window allocates
@@ -113,9 +114,11 @@ def run(cell: registry.Cell, seed: int, seconds: float, trace: bool,
     # each traced Pallas call's shapes and the work counted from them
     drv.counters["kernel_calls"] = {
         name: {"operands": ops, "results": res,
-               "work": kernel_work.work(tracing.stem(name), ops, res)}
+               "work": kernel_work.work(tracing.stem(name), ops, res,
+                                        cell.root)}
         for name, (ops, res) in red.calls.items()}
-    ctx = Context(red, tracing.load_peaks(used[0].device_kind), drv.counters)
+    ctx = Context(red, tracing.load_peaks(used[0].device_kind), drv.counters,
+                  cell.root)
     for m in cell.per_layer:
       v = cell.reader(m["name"])(ctx)
       if v is not None:

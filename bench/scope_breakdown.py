@@ -31,8 +31,9 @@ def breakdown(cell: registry.Cell, seed: int, seconds: float,
   from repro.util import compile_cache
   compile_cache()
   jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-  drv = drivers.KINDS[cell.traffic["kind"]](
-      cell.config, cell.traffic, seed, cell.chips, devices, seconds)
+  drv = drivers.driver_for(cell.traffic["kind"], cell.root)(
+      cell.config, cell.traffic, seed, cell.chips, devices, seconds,
+      root=cell.root)
   drv.setup()
   gc.collect()
   gc.freeze()

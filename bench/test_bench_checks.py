@@ -6,10 +6,11 @@ import time
 import jax
 import pytest
 
+from bench import registry, tiny
 from bench import run as R
-from bench import tiny
 
-ONE_CHIP = ["tiny-epoch", "marco-ingest", "marco-query"]
+BENCH = registry.load_benchmark()
+ONE_CHIP = [w["name"] for w in BENCH["workloads"] if w["chips"] == 1]
 SEED = 2 ** 31 + 12345
 
 
@@ -31,7 +32,8 @@ def test_cell_is_correct_as_configured(workload):
 
 @pytest.mark.parametrize("workload", ONE_CHIP)
 def test_lower_precision_control_is_not_correct(workload):
-  res = _run(tiny.cell(workload, feat_dtype="bfloat16"))
+  cell = tiny.cell(workload, feat_dtype="bfloat16")
+  res = _run(cell)
   assert not res["correct"]
-  gap = "feat_gap" if workload == "tiny-epoch" else "sieve_feat_gap"
+  gap = "feat_gap" if cell.traffic["kind"] == "epochs" else "sieve_feat_gap"
   assert res["checks"][gap]["value"] > 1e-4, res["checks"]

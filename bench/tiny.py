@@ -1,5 +1,6 @@
 """Cells shrunk to CPU size, for the benchmark's own tests: the same
-drivers, references and limits at a few thousand rows of width 32."""
+drivers, references and limits at a few thousand rows of width at most
+32."""
 from __future__ import annotations
 
 from bench import registry
@@ -10,8 +11,9 @@ def cell(workload: str, **config) -> registry.Cell:
   (``config`` overrides keys of the configuration after the cut)."""
   c = registry.Cell(registry.load_benchmark(), workload)
   cfg = c.config
-  cfg.update(d=32, append_block=256, kappa=8, k_final=8)
-  cfg["corpus"] = dict(cfg["corpus"], clusters=32)
+  cfg.update(d=min(int(cfg["d"]), 32), append_block=256, kappa=8, k_final=8)
+  if cfg["corpus"].get("kind", "clusters") == "clusters":
+    cfg["corpus"] = dict(cfg["corpus"], clusters=32)
   if "rows_per_chip" in cfg:
     cfg["rows_per_chip"] = 1024
   if "capacity_per_chip" in cfg:

@@ -21,7 +21,7 @@ import json
 import pathlib
 import re
 
-from bench import kernel_work
+from bench import kernel_work, registry
 
 OPS_LINE = "XLA Ops"
 WINDOW_SPAN = "bench.window"
@@ -219,15 +219,17 @@ def load_peaks(device_kind: str) -> dict:
   return table[device_kind]
 
 
-def roofline_pct(red: Reduction, kernel: str, peaks: dict) -> float | None:
+def roofline_pct(red: Reduction, kernel: str, peaks: dict,
+                 root=registry.ROOT) -> float | None:
   """Share (%) of the least time the chip could take for every traced call
   of ``kernel`` (by stem) in the time those calls took.  None when the
-  kernel did not run or its work is unknown."""
+  kernel did not run or its work is unknown (``kernel_work.work`` under
+  ``root``)."""
   least, took = 0.0, 0.0
   for name, (operands, results) in red.calls.items():
     if stem(name) != kernel or name not in red.op_s:
       continue
-    work = kernel_work.work(kernel, operands, results)
+    work = kernel_work.work(kernel, operands, results, root)
     if work is None:
       return None
     flops, moved = work
