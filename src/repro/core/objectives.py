@@ -349,19 +349,28 @@ class InformationGain:
     k_sc = jnp.where(row_live, k_sc, 0.0)
     return jax.scipy.linalg.solve_triangular(state.chol, k_sc, lower=True)
 
+  @staticmethod
+  def _linv(state: IGState) -> Array:
+    """The masked inverse factor the fused oracles consume: Cholesky
+    state upkeep, so it is scoped with ``update``."""
+    with jax.named_scope("info_gain.update"):
+      return _masked_linv(state.chol, state.count)
+
   def gains(self, state, cand_feats: Array) -> Array:
     state = self._state(state)
     s2 = self.sigma ** 2
     if self.kernel in dispatch.FUSED_SIMS:
       fn = dispatch.resolve("info_gain_cond", self.backend)
-      cond = fn(state.sel_feats, _masked_linv(state.chol, state.count),
-                cand_feats, kernel=self.kernel,
-                h=_kernel_h(self.kernel_kwargs), ridge=s2)
-    else:
+      linv = self._linv(state)
+      with jax.named_scope("info_gain.oracle"):
+        cond = fn(state.sel_feats, linv, cand_feats, kernel=self.kernel,
+                  h=_kernel_h(self.kernel_kwargs), ridge=s2)
+        return 0.5 * jnp.log(cond / s2)
+    with jax.named_scope("info_gain.oracle"):
       c = self._cross(state, cand_feats)                   # (k_max, nc)
       k_vv = jax.vmap(lambda x: self._k(x[None], x[None])[0, 0])(cand_feats)
       cond = jnp.maximum(k_vv + s2 - jnp.sum(c * c, axis=0), 1e-12)
-    return 0.5 * jnp.log(cond / s2)
+      return 0.5 * jnp.log(cond / s2)
 
   def select(self, state, cand_feats: Array,
              feasible: Array) -> tuple[Array, Array]:
@@ -369,28 +378,34 @@ class InformationGain:
     s2 = self.sigma ** 2
     if self.kernel in dispatch.FUSED_SIMS:
       fn = dispatch.resolve_select("info_gain_cond", self.backend)
-      cond, idx = fn(state.sel_feats, _masked_linv(state.chol, state.count),
-                     cand_feats, feasible, kernel=self.kernel,
-                     h=_kernel_h(self.kernel_kwargs), ridge=s2)
-      return 0.5 * jnp.log(jnp.maximum(cond, 1e-12) / s2), idx
+      linv = self._linv(state)
+      with jax.named_scope("info_gain.oracle"):
+        cond, idx = fn(state.sel_feats, linv, cand_feats, feasible,
+                       kernel=self.kernel, h=_kernel_h(self.kernel_kwargs),
+                       ridge=s2)
+        return 0.5 * jnp.log(jnp.maximum(cond, 1e-12) / s2), idx
     return masked_top1(self.gains(state, cand_feats), feasible)
 
   def update(self, state, feat: Array):
     if isinstance(state, IGShardState):
       return IGShardState(self.update(state.inner, feat), state.n_live)
-    s2 = self.sigma ** 2
-    c = self._cross(state, feat[None, :])[:, 0]            # (k_max,)
-    k_vv = self._k(feat[None], feat[None])[0, 0]
-    diag = jnp.sqrt(jnp.maximum(k_vv + s2 - jnp.sum(c * c), 1e-12))
-    i = state.count
-    # Write row i of the Cholesky: [c_0..c_{i-1}, diag, 0...]; keep the
-    # identity padding on the diagonal for rows > i.
-    row = jnp.where(jnp.arange(self.k_max) < i, c, 0.0)
-    row = row.at[i].set(diag)
-    chol = jax.lax.dynamic_update_slice(state.chol, row[None, :], (i, 0))
-    sel = jax.lax.dynamic_update_slice(state.sel_feats, feat[None, :], (i, 0))
-    gain = 0.5 * jnp.log(jnp.maximum(diag * diag, 1e-12) / s2)
-    return IGState(sel, i + 1, chol, state.value + gain)
+    with jax.named_scope("info_gain.update"):
+      # a row stored in bfloat16 enters the float32 state exactly
+      feat = feat.astype(state.sel_feats.dtype)
+      s2 = self.sigma ** 2
+      c = self._cross(state, feat[None, :])[:, 0]          # (k_max,)
+      k_vv = self._k(feat[None], feat[None])[0, 0]
+      diag = jnp.sqrt(jnp.maximum(k_vv + s2 - jnp.sum(c * c), 1e-12))
+      i = state.count
+      # Write row i of the Cholesky: [c_0..c_{i-1}, diag, 0...]; keep the
+      # identity padding on the diagonal for rows > i.
+      row = jnp.where(jnp.arange(self.k_max) < i, c, 0.0)
+      row = row.at[i].set(diag)
+      chol = jax.lax.dynamic_update_slice(state.chol, row[None, :], (i, 0))
+      sel = jax.lax.dynamic_update_slice(state.sel_feats, feat[None, :],
+                                         (i, 0))
+      gain = 0.5 * jnp.log(jnp.maximum(diag * diag, 1e-12) / s2)
+      return IGState(sel, i + 1, chol, state.value + gain)
 
   def value(self, state) -> Array:
     return self._state(state).value

@@ -528,6 +528,37 @@ def test_named_scopes_reach_the_hlo_and_change_no_instruction(monkeypatch):
     assert _n_instructions(scoped[name]) == _n_instructions(bare[name])
 
 
+def _epoch_hlo(svc) -> str:
+  import jax
+  import jax.numpy as jnp
+  st = svc.store
+  ages = jnp.asarray(svc.board.ages(), jnp.float32)
+  return svc._epoch_fn.lower(st.feats, st.gids, st.ubound_device, ages,
+                             jnp.float32(np.inf), jax.random.PRNGKey(0)
+                             ).compile().as_text()
+
+
+@pytest.mark.parametrize("mode", ["standard", "lazy"])
+def test_info_gain_scopes_reach_the_epoch_hlo(monkeypatch, mode):
+  """An info-gain epoch carries the oracle sweep and the Cholesky upkeep
+  in their own scopes, nested in the GreeDi rounds, and compiles to as
+  many instructions without them."""
+  import contextlib
+  import re
+  import jax
+  kw = dict(objective="info_gain", kernel="rbf", mode=mode, sieve=False)
+  scoped = _epoch_hlo(_service(**kw))
+  for outer in ("greedi.round1", "greedi.round2"):
+    for scope in ("info_gain.oracle", "info_gain.update"):
+      assert re.search(rf"{outer}/[^\"]*\b{scope}[/)]", scoped), (
+          outer, scope)
+  monkeypatch.setattr(jax, "named_scope",
+                      contextlib.contextmanager(lambda name: (yield)))
+  bare = _epoch_hlo(_service(**kw))
+  assert "info_gain." not in bare.replace("info_gain.py", "")
+  assert _n_instructions(scoped) == _n_instructions(bare)
+
+
 def test_append_reads_sieve_diagnostics_once_per_call(monkeypatch):
   """With obs on, one append of several chunks crosses D2H once for its
   device-fed diagnostics, and its counters end equal to the sums of the

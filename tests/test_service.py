@@ -541,3 +541,38 @@ def test_service_info_gain_warm_parity_sharded(subrun):
       assert sels[True] == sels[False], sels
       print("PARITY_OK")
       """, 4)
+
+
+@pytest.mark.parametrize("sigma", [0.5, 2.0])
+def test_service_sigma_reaches_the_info_gain_objective(sigma):
+  """A service given an ``InformationGain(sigma=...)`` instance selects and
+  values as a direct greedy of that instance over the epoch's partition
+  order (one shard, kappa = k_final: round 2 re-picks round 1's coreset),
+  differs in value from the sigma = 1 default, and takes its prior warm
+  bound at that sigma."""
+  from repro.core.partition import partition_perm
+  n, k = 256, 8
+  f = np.asarray(_feats(5, n, 6))
+  key = jax.random.PRNGKey(11)
+
+  def info_gain(s):
+    return O.InformationGain(k_max=k, kernel="rbf",
+                             kernel_kwargs=(("h", 0.75),), sigma=s)
+
+  obj = info_gain(sigma)
+  perm = np.asarray(partition_perm(jax.random.split(key)[0], n))
+  xp = jnp.asarray(f[perm])
+  want = greedy(obj, obj.init(xp), xp, k)
+  got = {}
+  for s in (sigma, 1.0):
+    svc = _service(d=6, kappa=k, k_final=k, capacity=n, append_block=128,
+                   objective=info_gain(s), mode="standard", warm_start=False)
+    svc.append(f)
+    got[s] = svc.epoch(rng=key)
+  assert sorted(got[sigma].sel_gids.tolist()) == sorted(
+      perm[np.asarray(want.idx)].tolist())
+  np.testing.assert_allclose(got[sigma].stats.value,
+                             float(want.values[-1]), rtol=1e-5)
+  assert (got[1.0].stats.value > got[sigma].stats.value) == (sigma > 1.0)
+  warm = _service(d=6, objective=info_gain(sigma))
+  assert warm.warm and warm.store._maintainer.sigma == sigma

@@ -116,6 +116,15 @@ def _info_select():
               ((n, D), jnp.float32), ((n,), jnp.float32)]
 
 
+def _info_select_d6():
+  """The GP active-set deployment's round-1 pass: one chip's share of the
+  click log, 11,468,800 rows of 6-d user features, in one call."""
+  n, k, d = 11468800, 64, 6
+  fn = functools.partial(info_select_pallas, block_n=_blk(n, d))
+  return fn, [((k, d), jnp.float32), ((k, k), jnp.float32),
+              ((n, d), jnp.float32), ((n,), jnp.float32)]
+
+
 def _graph_cut_select():
   n = 4096
   fn = functools.partial(graph_cut_select_pallas, block_m=_blk(n, n),
@@ -139,6 +148,7 @@ CASES = {
     "facility_select_round2": _facility_select_round2,
     "coverage_select": _coverage_select,
     "info_select": _info_select,
+    "info_select_d6": _info_select_d6,
     "graph_cut_select": _graph_cut_select,
     "pairwise": _pairwise,
 }
@@ -150,6 +160,49 @@ def test_kernel_compiles_for_v5e(one_chip, name):
   args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
   compiled = jax.jit(fn).lower(*args).compile()
   assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_info_gain_epoch_calls_info_select_for_v5e(one_chip, monkeypatch):
+  """The standard-mode information-gain epoch of the GP active-set
+  deployment (one chip's 11,468,800 6-d rows, kappa = k_final = 64),
+  compiled for the chip, calls the fused select kernel under the instruction
+  name ``info_select`` for round 1 and the gain kernel ``info_gain_cond``
+  for round 2: the names the device trace gives those kernels and the
+  benchmark's kernel work reads.  Its resident block and temporaries fit
+  the chip's 16 GB."""
+  import re
+
+  import numpy as np
+  from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+  from repro.kernels import ops
+  from repro.service import SelectionService
+  from repro.util import make_mesh
+
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  monkeypatch.setattr(autotune, "default_backend", lambda: "tpu")
+  # the process may have cached interpret mode from an earlier CPU test
+  monkeypatch.setattr(ops, "_interpret", lambda: False)
+  cap, d = 11_468_800, 6
+  svc = SelectionService(make_mesh((1,), ("data",)), d=d, kappa=64,
+                         k_final=64, kernel="rbf",
+                         kernel_kwargs=(("h", 0.75),), backend="pallas",
+                         mode="standard", warm_start=False,
+                         objective="info_gain", sieve=False)
+  svc.mesh = Mesh(np.array(list(one_chip.device_set)), ("data",))
+  rows = NamedSharding(svc.mesh, P("data"))
+  rep = NamedSharding(svc.mesh, P())
+  args = [jax.ShapeDtypeStruct(s, dt, sharding=sh) for s, dt, sh in (
+      ((cap, d), jnp.float32, rows), ((cap,), jnp.int32, rows),
+      ((cap,), jnp.float32, rows), ((1,), jnp.float32, rep),
+      ((), jnp.float32, rep), ((2,), jnp.uint32, rep))]
+  compiled = jax.jit(svc._epoch_raw).lower(*args).compile()
+  mem = compiled.memory_analysis()
+  assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9, mem
+  text = compiled.as_text()
+  calls = re.findall(r"%([A-Za-z_]+)\.\d+ = .*custom_call_target="
+                     r'"tpu_custom_call"', text)
+  assert sorted(calls) == ["info_gain_cond", "info_select"], calls
 
 
 def test_vmem_clamp_shrinks_wide_tiles():
